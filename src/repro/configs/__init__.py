@@ -1,6 +1,8 @@
 """Architecture registry.
 
 ``get_config("kimi-k2-1t-a32b")`` returns the exact published config;
+``get_config("deepseek-r1-chip")`` one chip's share of deepseek-r1 (see
+:mod:`repro.configs.deepseek_r1`);
 ``ASSIGNED_ARCHS`` lists the 10 graded architectures in assignment order.
 """
 
@@ -56,6 +58,7 @@ REGISTRY: Dict[str, ModelConfig] = {
         deepseek_r1,
     )
 }
+REGISTRY[deepseek_r1.CHIP.arch_id] = deepseek_r1.CHIP
 
 
 def get_config(arch_id: str) -> ModelConfig:

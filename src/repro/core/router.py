@@ -32,7 +32,10 @@ def route(params: Dict, x: jax.Array, cfg: MoEConfig,
     """
     from repro.core.types import RouterOutput
 
-    logits = x.astype(jnp.float32) @ params["w_router"]     # (T, E)
+    # full f32 even on TPU, whose default f32 matmul is one bf16 pass: a
+    # near-tie then flips the top-k between replicas and the reference
+    logits = jnp.matmul(x.astype(jnp.float32), params["w_router"],
+                        precision=jax.lax.Precision.HIGHEST)  # (T, E)
     if bias is not None:
         logits = logits + bias.astype(jnp.float32)
     if cfg.router_score_fn == "softmax":

@@ -8,7 +8,6 @@
 * :mod:`repro.kernels.combine` — fused top-k combine epilogue.
 * :mod:`repro.kernels.ops` — jit wrappers + CPU lowerings.
 * :mod:`repro.kernels.ref` — pure-jnp oracles.
-* :mod:`repro.kernels.compat` — Pallas API shims across jax versions.
 """
 
 from repro.kernels import ops, ref  # noqa: F401

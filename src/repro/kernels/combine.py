@@ -2,7 +2,9 @@
 protocol — weighted sum of the k returned expert partials per token.
 
 out[t] = sum_k w[t, k] * x[t, k, :].  Grid tiles (tokens, d_model); the tiny
-k dimension is kept whole per block.
+k dimension is kept whole per block and summed by a static loop of
+(tokens, d) multiply-adds — Mosaic does not lower the equivalent
+``einsum("tkd,tk->td")``, whose contraction dim sits between the others.
 """
 
 from __future__ import annotations
@@ -10,14 +12,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, w_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32)          # (TT, k, TD)
     w = w_ref[...].astype(jnp.float32)          # (TT, k)
-    o_ref[...] = jnp.einsum("tkd,tk->td", x, w).astype(o_ref.dtype)
+    acc = jnp.zeros(o_ref.shape, jnp.float32)   # (TT, TD)
+    for j in range(w.shape[1]):
+        acc = acc + x_ref[:, j, :].astype(jnp.float32) * w[:, j:j + 1]
+    o_ref[...] = acc.astype(o_ref.dtype)
 
 
 def combine_weighted_pallas(x: jax.Array, w: jax.Array, *, tt: int = 128,
@@ -35,6 +38,7 @@ def combine_weighted_pallas(x: jax.Array, w: jax.Array, *, tt: int = 128,
         ],
         out_specs=pl.BlockSpec((tt, td), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((T, d), x.dtype),
-        compiler_params=compiler_params(("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, w)

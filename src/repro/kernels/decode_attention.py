@@ -1,8 +1,12 @@
 """Pallas TPU flash-decode kernels: one-token GQA attention over a KV cache.
 
 The attention client's hot loop during decoding.  Online-softmax over KV
-blocks; grid = (batch, kv_heads, seq_blocks) with the sequence dimension
-innermost so the (G, hd) accumulator lives in VMEM scratch across blocks.
+blocks; grid = (batch, seq_blocks) with the sequence dimension innermost so
+the (KV, G, hd) accumulator lives in VMEM scratch across blocks.  A block
+holds every KV head, ``(1, ts, KV, hd)``: the TPU tiles the last two dims of
+a block, and a single-head block ``(1, ts, 1, hd)`` would leave a
+second-minor dim of 1 that is neither a multiple of 8 nor the full KV, which
+Mosaic refuses.  The kernel body loops over the KV heads statically.
 Sequence lengths arrive via scalar prefetch; padded cache slots are masked.
 
 Two variants share the kernel body:
@@ -13,11 +17,12 @@ Two variants share the kernel body:
 * :func:`paged_flash_decode_pallas` — block-pool cache: all sequences share
   one pool ``(num_blocks, bs, KV, hd)`` and each sequence names its blocks
   through a ``(B, max_blocks)`` block table.  The table rides scalar
-  prefetch, so the *index map* gathers: grid step ``(b, kv, s)`` DMAs pool
+  prefetch, so the *index map* gathers: grid step ``(b, s)`` DMAs pool
   block ``tables[b, s]`` — the kernel body never sees the indirection.
 
-VMEM per step: TS·hd (k) + TS·hd (v) + G·hd (q) + G·hd·4 (acc) — for
-TS=512, hd=128, G=8: ~0.5 MiB.
+VMEM per step: 2·TS·KV·hd (k, v) + H·hd (q) + H·hd·4 (acc) — for TS=512,
+KV=8, hd=64, H=128 in bf16: ~1.2 MiB before double buffering (the lane dim
+pads hd 64 to 128).
 """
 
 from __future__ import annotations
@@ -30,7 +35,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
 
 NEG = -1e30
 
@@ -38,7 +42,7 @@ NEG = -1e30
 def _kernel(lengths, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
             *, ts: int, n_s: int, scale: float):
     b = pl.program_id(0)
-    s = pl.program_id(2)
+    s = pl.program_id(1)
 
     @pl.when(s == 0)
     def _init():
@@ -46,29 +50,40 @@ def _kernel(lengths, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                  # (G, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (TS, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)               # (TS, hd)
-
     span = s * ts + jax.lax.broadcasted_iota(jnp.int32, (1, ts), 1)
     valid = span < lengths[b]                            # (1, TS)
 
-    scores = (q @ k.T) * scale                           # (G, TS)
-    scores = jnp.where(valid, scores, NEG)
+    for h in range(q_ref.shape[1]):                      # KV heads, static
+        q = q_ref[0, h].astype(jnp.float32)              # (G, hd)
+        k = k_ref[0, :, h, :].astype(jnp.float32)        # (TS, hd)
+        v = v_ref[0, :, h, :].astype(jnp.float32)        # (TS, hd)
 
-    m_prev = m_ref[...]                                  # (G, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-    # explicit mask: a fully-invalid block must contribute nothing
-    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)   # (G, TS)
-    alpha = jnp.exp(m_prev - m_new)                      # (G, 1)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + p @ v
-    m_ref[...] = m_new
+        scores = jax.lax.dot_general(                    # (G, TS)
+            q, k, (((1,), (1,)), ((), ()))) * scale
+        scores = jnp.where(valid, scores, NEG)
+
+        m_prev = m_ref[h]                                # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        # explicit mask: a fully-invalid block must contribute nothing
+        p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)                  # (G, 1)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + p @ v
+        m_ref[h] = m_new
 
     @pl.when(s == n_s - 1)
     def _finalize():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _scratch(KV: int, G: int, hd: int):
+    return [pltpu.VMEM((KV, G, hd), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32)]
+
+
+_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 def flash_decode_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -91,23 +106,18 @@ def flash_decode_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, KV, n_s),
+            grid=(B, n_s),
             in_specs=[
-                pl.BlockSpec((1, 1, G, hd), lambda b, kv, s, L: (b, kv, 0, 0)),
-                pl.BlockSpec((1, ts, 1, hd), lambda b, kv, s, L: (b, s, kv, 0)),
-                pl.BlockSpec((1, ts, 1, hd), lambda b, kv, s, L: (b, s, kv, 0)),
+                pl.BlockSpec((1, KV, G, hd), lambda b, s, L: (b, 0, 0, 0)),
+                pl.BlockSpec((1, ts, KV, hd), lambda b, s, L: (b, s, 0, 0)),
+                pl.BlockSpec((1, ts, KV, hd), lambda b, s, L: (b, s, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, G, hd),
-                                   lambda b, kv, s, L: (b, kv, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, hd), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-            ],
+            out_specs=pl.BlockSpec((1, KV, G, hd),
+                                   lambda b, s, L: (b, 0, 0, 0)),
+            scratch_shapes=_scratch(KV, G, hd),
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
     return out.reshape(B, H, hd)
@@ -146,28 +156,22 @@ def paged_flash_decode_pallas(q: jax.Array, k_pool: jax.Array,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, KV, n_s),
+            grid=(B, n_s),
             in_specs=[
-                pl.BlockSpec((1, 1, G, hd),
-                             lambda b, kv, s, T, L: (b, kv, 0, 0)),
+                pl.BlockSpec((1, KV, G, hd), lambda b, s, T, L: (b, 0, 0, 0)),
                 # the paged gather: block s of sequence b is pool block
                 # T[b, s] — the DMA indirection lives in the index map
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, kv, s, T, L: (T[b, s], 0, kv, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, kv, s, T, L: (T[b, s], 0, kv, 0)),
+                pl.BlockSpec((1, bs, KV, hd),
+                             lambda b, s, T, L: (T[b, s], 0, 0, 0)),
+                pl.BlockSpec((1, bs, KV, hd),
+                             lambda b, s, T, L: (T[b, s], 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, G, hd),
-                                   lambda b, kv, s, T, L: (b, kv, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, hd), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-            ],
+            out_specs=pl.BlockSpec((1, KV, G, hd),
+                                   lambda b, s, T, L: (b, 0, 0, 0)),
+            scratch_shapes=_scratch(KV, G, hd),
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, k_pool, v_pool)

@@ -22,7 +22,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import group_shrink as gs
-from repro.kernels.compat import compiler_params
 
 
 def _kernel(tile_gid, tile_valid, x_ref, w_ref, o_ref, *, n_k: int):
@@ -75,8 +74,8 @@ def grouped_gemm_pallas(x_sorted: jax.Array, w: jax.Array,
             out_specs=pl.BlockSpec((tm, tn), lambda i, j, k, gid, vld: (i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((T * tm, N), jnp.float32),
-        compiler_params=compiler_params(
-            ("arbitrary", "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(table.tile_gid, table.tile_valid, x_pad, w)
 

@@ -4,14 +4,23 @@ Every op has several implementations:
 
 * ``pallas`` / ``pallas_interpret`` — the TPU kernel (interpret=True runs the
   kernel body in Python on CPU; used by the allclose tests).
-* ``xla_ragged`` — ``jax.lax.ragged_dot``: exact, executes fast on CPU; its
-  HLO flop count on CPU over-counts by G× (XLA decomposes into masked dots),
-  so it is NOT used for the roofline dry-run.
-* ``xla_dense`` — per-expert-capacity batched matmul (GShard-style): the
-  flop-honest XLA lowering used by the dry-run; FLOPs = 2·L·cap·d·f which at
-  the configured capacity factor equals the ideal grouped-GEMM work.
 * ``ref`` — the oracle from :mod:`repro.kernels.ref`.
 
+The grouped GEMM has two more:
+
+* ``xla_ragged`` — ``jax.lax.ragged_dot``: exact, executes fast on CPU; its
+  HLO flop count on CPU over-counts by G× (XLA decomposes into masked dots),
+  so it is NOT used for the roofline dry-run.  The TPU compiler refuses a
+  *batched* ragged dot, which is what ``vmap`` over the logical expert
+  servers makes of it.
+* ``xla_dense`` — per-expert-capacity batched matmul (GShard-style): the
+  flop-honest XLA lowering used by the dry-run; FLOPs = 2·L·cap·d·f which at
+  the configured capacity factor equals the ideal grouped-GEMM work.  Rows
+  past an expert's capacity are dropped, so it is not exact under skew.
+
+``impl="auto"`` picks from the platform: ``pallas`` on TPU; on the CPU
+``xla_ragged`` for the grouped GEMM and ``ref`` (the plain XLA lowering)
+for the others.  An op given an implementation it does not have raises.
 ``set_default_impl`` lets the launch layer pick one globally (the dry-run
 sets ``xla_dense``; tests pin impls explicitly).
 """
@@ -37,18 +46,19 @@ def set_default_impl(impl: Optional[str]) -> None:
     _DEFAULT_IMPL = impl
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+_KERNEL_IMPLS = ("ref", "pallas", "pallas_interpret")
+_GEMM_IMPLS = _KERNEL_IMPLS + ("xla_ragged", "xla_dense")
 
 
-def _resolve(impl: str) -> str:
+def _resolve(op: str, impl: str, cpu_impl: str, have) -> str:
     if impl == "auto":
         if _DEFAULT_IMPL is not None:
-            return _DEFAULT_IMPL
-        return "pallas" if _on_tpu() else "xla_ragged"
+            impl = _DEFAULT_IMPL
+        else:
+            impl = "pallas" if jax.default_backend() == "tpu" else cpu_impl
+    if impl not in have:
+        raise ValueError(f"{op} has no implementation {impl!r}; "
+                         f"expected one of {have} or 'auto'")
     return impl
 
 
@@ -88,7 +98,7 @@ def grouped_gemm(x_sorted: jax.Array, w: jax.Array, group_sizes: jax.Array,
                  *, impl: str = "auto", expert_capacity: Optional[int] = None,
                  tm: int = 128, tn: int = 128, tk: int = 128) -> jax.Array:
     """out[i] = x_sorted[i] @ w[g(i)] — see module docstring for impls."""
-    impl = _resolve(impl)
+    impl = _resolve("grouped_gemm", impl, "xla_ragged", _GEMM_IMPLS)
     if impl == "ref":
         return kref.grouped_gemm_ref(x_sorted, w, group_sizes)
     if impl == "xla_ragged":
@@ -100,11 +110,9 @@ def grouped_gemm(x_sorted: jax.Array, w: jax.Array, group_sizes: jax.Array,
         M, G = x_sorted.shape[0], w.shape[0]
         cap = expert_capacity or max(_ceil_mult(2 * M // max(G, 1) + 1, 8), 8)
         return grouped_gemm_dense(x_sorted, w, group_sizes, cap)
-    if impl in ("pallas", "pallas_interpret"):
-        return grouped_gemm_pallas(
-            x_sorted, w, group_sizes, tm=tm, tn=tn, tk=tk,
-            interpret=(impl == "pallas_interpret"))
-    raise ValueError(f"unknown grouped_gemm impl {impl!r}")
+    return grouped_gemm_pallas(
+        x_sorted, w, group_sizes, tm=tm, tn=tn, tk=tk,
+        interpret=(impl == "pallas_interpret"))
 
 
 def _ceil_mult(x: int, m: int) -> int:
@@ -116,38 +124,32 @@ def _ceil_mult(x: int, m: int) -> int:
 def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                  lengths: jax.Array, *, impl: str = "auto",
                  ts: int = 512) -> jax.Array:
-    impl = _resolve(impl)
-    if impl in ("ref", "xla_ragged", "xla_dense"):
+    impl = _resolve("flash_decode", impl, "ref", _KERNEL_IMPLS)
+    if impl == "ref":
         return kref.flash_decode_ref(q, k_cache, v_cache, lengths)
-    if impl in ("pallas", "pallas_interpret"):
-        return flash_decode_pallas(q, k_cache, v_cache, lengths, ts=ts,
-                                   interpret=(impl == "pallas_interpret"))
-    raise ValueError(f"unknown flash_decode impl {impl!r}")
+    return flash_decode_pallas(q, k_cache, v_cache, lengths, ts=ts,
+                               interpret=(impl == "pallas_interpret"))
 
 
 def paged_flash_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                        block_tables: jax.Array, lengths: jax.Array, *,
                        impl: str = "auto") -> jax.Array:
     """Flash decode over a shared block pool gathered through block tables."""
-    impl = _resolve(impl)
-    if impl in ("ref", "xla_ragged", "xla_dense"):
+    impl = _resolve("paged_flash_decode", impl, "ref", _KERNEL_IMPLS)
+    if impl == "ref":
         return kref.paged_flash_decode_ref(q, k_pool, v_pool, block_tables,
                                            lengths)
-    if impl in ("pallas", "pallas_interpret"):
-        return paged_flash_decode_pallas(
-            q, k_pool, v_pool, block_tables, lengths,
-            interpret=(impl == "pallas_interpret"))
-    raise ValueError(f"unknown paged_flash_decode impl {impl!r}")
+    return paged_flash_decode_pallas(
+        q, k_pool, v_pool, block_tables, lengths,
+        interpret=(impl == "pallas_interpret"))
 
 
 # -------------------------------------------------------------------- combine
 
 def combine_weighted(x: jax.Array, w: jax.Array, *, impl: str = "auto",
                      tt: int = 128, td: int = 512) -> jax.Array:
-    impl = _resolve(impl)
-    if impl in ("ref", "xla_ragged", "xla_dense"):
+    impl = _resolve("combine_weighted", impl, "ref", _KERNEL_IMPLS)
+    if impl == "ref":
         return kref.combine_weighted_ref(x, w)
-    if impl in ("pallas", "pallas_interpret"):
-        return combine_weighted_pallas(x, w, tt=tt, td=td,
-                                       interpret=(impl == "pallas_interpret"))
-    raise ValueError(f"unknown combine impl {impl!r}")
+    return combine_weighted_pallas(x, w, tt=tt, td=td,
+                                   interpret=(impl == "pallas_interpret"))

@@ -54,13 +54,15 @@ token streams in both modes; only timing (TTFT/ITL/throughput) moves.
 
 from __future__ import annotations
 
+import functools
 from collections import deque
 from dataclasses import dataclass
-from typing import Callable, Deque, Optional
+from typing import Callable, Deque, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import ModelConfig
 from repro.core.elastic import ServerPool
@@ -91,7 +93,8 @@ class EngineConfig:
     restart_steps: int = 50            # monolithic group restart cost
     tp_restart_steps: int = 12         # one TP unit restart
     tp_batch_cap: Optional[int] = None # TP: weight replication caps batch
-    gemm_impl: str = "xla_ragged"
+    # "auto": the Pallas grouped GEMM on TPU, ragged_dot on the CPU
+    gemm_impl: str = "auto"
     eos_token: Optional[int] = None
     # --- scheduler knobs -------------------------------------------------
     # max prompt tokens per prefill step (0 = whole prompt, the pre-split
@@ -172,6 +175,29 @@ class EngineConfig:
     # steps always feed it); prompt-heavy workloads then trigger rebalances
     # from prefill pressure, not only after decoding starts
     prefill_load_feedback: bool = True
+
+
+def init_params(cfg: ModelConfig, num_servers: int, redundant_table,
+                key: jax.Array):
+    """The served model's params from one jitted init placed on the
+    default device.  Run eagerly, every intermediate (f32 draws before the
+    bf16 cast, the per-server gather of each expert bank) would be
+    materialised at once — several times the jitted peak at real widths."""
+    red = None if redundant_table is None else tuple(
+        map(tuple, np.asarray(redundant_table).tolist()))
+    return _jitted_init(cfg, num_servers, red)(key)
+
+
+@functools.lru_cache(maxsize=8)
+def _jitted_init(cfg: ModelConfig, num_servers: int,
+                 red: Optional[Tuple[Tuple[int, ...], ...]]):
+    # one compile per (config, pool shape) — engines of a test or a cluster
+    # share it
+    table = None if red is None else np.asarray(red, np.int32).reshape(
+        num_servers, -1)
+    model = build_model(cfg, num_servers=num_servers, redundant_table=table)
+    return jax.jit(model.init_params,
+                   out_shardings=SingleDeviceSharding(jax.devices()[0]))
 
 
 class ServingEngine:
@@ -259,9 +285,11 @@ class ServingEngine:
                 "exec_mode='async' needs a model family with a uniform "
                 "cache batch axis (decoder family) — wave decodes mask "
                 "inactive slot rows")
-        key = jax.random.PRNGKey(seed)
-        params = params if params is not None else \
-            self.model.init_params(key)
+        if params is None:
+            params = init_params(cfg, S if cfg.moe else 1,
+                                 self.pool.redundant_table
+                                 if self.pool else None,
+                                 jax.random.PRNGKey(seed))
         self.monitor = Monitor(heartbeat_timeout=3.0)
         if self.pool:
             self.monitor.subscribe_server_down(self.pool.server_failed)

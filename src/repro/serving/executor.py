@@ -50,7 +50,7 @@ class Executor:
     """Owns params + caches + jitted step variants for one engine."""
 
     def __init__(self, model: Model, params, pool, *, max_batch: int,
-                 max_seq: int, gemm_impl: str = "xla_ragged",
+                 max_seq: int, gemm_impl: str = "auto",
                  decode_mode: str = "lockstep", kv_mode: str = "dense",
                  kv_block_size: int = 16, kv_num_blocks: int = 0):
         assert decode_mode in ("lockstep", "pipelined", "serialized"), \

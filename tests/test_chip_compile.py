@@ -1,0 +1,134 @@
+"""Compile rehearsals for one TPU v5e chip, without the chip.
+
+The TPU compiler is installed with jax and compiles for a described
+topology: each test lowers a Pallas kernel, or the served decode step, at
+the ``deepseek-r1-chip`` widths and compiles it, which raises what the
+chip's compiler would raise (unaligned blocks, refused kernel bodies,
+programs that do not fit).  Nothing runs, so nothing here says anything
+about results or times.
+
+Only one process at a time may load the TPU library, so the topology is
+described inside a module fixture, never at import: every pytest worker
+collects the same tests and only the one given this file loads it.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core.elastic import ServerPool
+from repro.kernels import ops
+from repro.models.transformer import ParallelCtx, build_model
+
+HBM_BYTES = 16 * 2 ** 30          # one v5e chip
+CFG = get_config("deepseek-r1-chip")
+BATCH, SEQ, CHUNK = 32, 2048, 64  # the smoke's engine shape
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()   # the kernel is there
+    return compiled
+
+
+@pytest.mark.parametrize("k,n", [(CFG.d_model, CFG.moe.d_expert),
+                                 (CFG.moe.d_expert, CFG.d_model)],
+                         ids=["gate_up", "down"])
+def test_grouped_gemm_compiles(one_chip, k, n):
+    groups = 6                    # 4 primaries + 2 redundant slots
+    _compile(lambda x, w, g: ops.grouped_gemm(x, w, g, impl="pallas"),
+             _spec(one_chip, (256, k), jnp.bfloat16),
+             _spec(one_chip, (groups, k, n), jnp.bfloat16),
+             _spec(one_chip, (groups,), jnp.int32))
+
+
+def test_flash_decode_compiles(one_chip):
+    kv = (BATCH, SEQ, CFG.num_kv_heads, CFG.head_dim)
+    _compile(lambda q, k, v, n: ops.flash_decode(q, k, v, n, impl="pallas"),
+             _spec(one_chip, (BATCH, CFG.num_heads, CFG.head_dim),
+                   jnp.bfloat16),
+             _spec(one_chip, kv, jnp.bfloat16),
+             _spec(one_chip, kv, jnp.bfloat16),
+             _spec(one_chip, (BATCH,), jnp.int32))
+
+
+def test_paged_flash_decode_compiles(one_chip):
+    bs = 16                       # EngineConfig.kv_block_size
+    per_seq = SEQ // bs
+    pool = (BATCH * per_seq + 1, bs, CFG.num_kv_heads, CFG.head_dim)
+    _compile(lambda q, k, v, t, n: ops.paged_flash_decode(
+        q, k, v, t, n, impl="pallas"),
+        _spec(one_chip, (BATCH, CFG.num_heads, CFG.head_dim), jnp.bfloat16),
+        _spec(one_chip, pool, jnp.bfloat16),
+        _spec(one_chip, pool, jnp.bfloat16),
+        _spec(one_chip, (BATCH, per_seq), jnp.int32),
+        _spec(one_chip, (BATCH,), jnp.int32))
+
+
+def test_combine_weighted_compiles(one_chip):
+    T, k = 256, CFG.moe.top_k
+    _compile(lambda x, w: ops.combine_weighted(x, w, impl="pallas"),
+             _spec(one_chip, (T, k, CFG.d_model), jnp.bfloat16),
+             _spec(one_chip, (T, k), jnp.float32))
+
+
+def test_decode_step_fits_one_chip(one_chip):
+    """The served decode step of the chip cut, as the executor jits it
+    (params, tokens, cache and the runtime placement arrays are arguments),
+    with the expert GEMM ``auto`` picks on TPU."""
+    pool = ServerPool(CFG, 4, tokens_per_client=max(BATCH, CHUNK),
+                      n_redundant=2)
+    model = build_model(CFG, num_servers=4,
+                        redundant_table=pool.redundant_table)
+    rt = pool.runtime("pallas")
+
+    def spec(tree):
+        return jax.tree.map(
+            lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    def decode(params, tokens, cache, rt_arrays):
+        mapping, alive, local, bias = rt_arrays
+        ctx = ParallelCtx(moe_runtime=rt._replace(
+            mapping=mapping, alive=alive, local_table=local,
+            route_bias=bias), remat=False)
+        logits, cache, st = model.decode_step(params, tokens, cache, ctx)
+        return logits, cache, st.expert_load
+
+    compiled = _compile(
+        decode,
+        spec(jax.eval_shape(model.init_params, jax.random.PRNGKey(0))),
+        _spec(one_chip, (BATCH, 1), jnp.int32),
+        spec(model.init_cache(BATCH, SEQ, abstract=True)),
+        spec((rt.mapping, rt.alive, rt.local_table, rt.route_bias)))
+    mem = compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < HBM_BYTES, peak / 2 ** 30

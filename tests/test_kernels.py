@@ -67,6 +67,7 @@ def test_tile_table_shrinks_inactive_groups():
     (2, 8, 2, 32, 64, 16),
     (3, 4, 4, 64, 128, 32),
     (1, 16, 8, 16, 32, 8),
+    (2, 128, 8, 64, 64, 32),      # deepseek-r1 head layout
 ])
 def test_flash_decode_vs_ref(b, h, kv, hd, s, ts, dtype, rng):
     q = jnp.asarray(rng.normal(size=(b, h, hd)), dtype)
@@ -83,10 +84,46 @@ def test_flash_decode_vs_ref(b, h, kv, hd, s, ts, dtype, rng):
 
 
 @pytest.mark.parametrize("t,k,d,tt,td", [(64, 2, 128, 16, 32),
-                                         (128, 8, 256, 32, 64)])
+                                         (128, 8, 256, 32, 64),
+                                         (16, 8, 7168, 8, 512)])  # r1 top-8
 def test_combine_vs_ref(t, k, d, tt, td, rng):
     x = jnp.asarray(rng.normal(size=(t, k, d)), jnp.float32)
     w = jnp.asarray(rng.random(size=(t, k)), jnp.float32)
     out = ops.combine_weighted(x, w, impl="pallas_interpret", tt=tt, td=td)
     exp = ref.combine_weighted_ref(x, w)
     np.testing.assert_allclose(out, exp, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("op,impl", [
+    ("flash_decode", "xla_ragged"), ("flash_decode", "xla_dense"),
+    ("paged_flash_decode", "xla_ragged"), ("combine_weighted", "xla_dense"),
+    ("grouped_gemm", "mosaic"),
+])
+def test_ops_refuse_impl_they_do_not_have(op, impl, rng):
+    """An impl name an op does not implement raises; it never falls back
+    to the reference."""
+    q = jnp.zeros((1, 4, 16))
+    kv = jnp.zeros((1, 16, 2, 16))
+    calls = {
+        "flash_decode": lambda: ops.flash_decode(
+            q, kv, kv, jnp.ones((1,), jnp.int32), impl=impl),
+        "paged_flash_decode": lambda: ops.paged_flash_decode(
+            q, kv, kv, jnp.zeros((1, 1), jnp.int32),
+            jnp.ones((1,), jnp.int32), impl=impl),
+        "combine_weighted": lambda: ops.combine_weighted(
+            jnp.zeros((8, 2, 16)), jnp.zeros((8, 2)), impl=impl),
+        "grouped_gemm": lambda: ops.grouped_gemm(
+            jnp.zeros((8, 16)), jnp.zeros((2, 16, 16)),
+            jnp.asarray([4, 4], jnp.int32), impl=impl),
+    }
+    with pytest.raises(ValueError, match="no implementation"):
+        calls[op]()
+
+
+def test_ops_auto_picks_from_platform():
+    """On the CPU "auto" is ragged_dot for the grouped GEMM and the plain
+    XLA lowering for the others; Pallas is what it picks on TPU."""
+    assert ops._resolve("grouped_gemm", "auto", "xla_ragged",
+                        ops._GEMM_IMPLS) == "xla_ragged"
+    assert ops._resolve("flash_decode", "auto", "ref",
+                        ops._KERNEL_IMPLS) == "ref"

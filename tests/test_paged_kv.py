@@ -61,6 +61,7 @@ def _tokens(res):
     (2, 8, 2, 32, 16, 4),
     (3, 4, 4, 64, 32, 2),
     (1, 16, 8, 16, 8, 4),
+    (2, 128, 8, 64, 16, 3),       # deepseek-r1 head layout
 ])
 def test_paged_flash_decode_vs_ref(b, h, kv, hd, bs, mb, dtype, rng):
     nb = b * mb + 1
