@@ -43,6 +43,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro import tracing  # noqa: E402
+
 ARCH = "deepseek-r1-chip"
 GiB = 2 ** 30
 
@@ -87,29 +89,15 @@ def require_tpu() -> dict:
             "count": len(devs)}
 
 
-class CompileCounter:
-    """Backend compile seconds and persistent-cache hits, from JAX's own
-    monitoring events."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.compiles += 1
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def line(self) -> str:
-        return (f"backend compiles {self.compiles}, {self.seconds:.1f} s; "
-                f"persistent-cache hits {self.cache_hits}")
+def compiles_line() -> str:
+    """The process's compiles so far, from :mod:`repro.tracing`'s
+    counters."""
+    c = tracing.counters()
+    return (f"backend compiles {c.get('jax.compiles', 0):.0f}, "
+            f"{c.get('jax.compile_s', 0.0):.1f} s; persistent-cache hits "
+            f"{c.get('jax.cache_hits', 0):.0f}; inside engine steps "
+            f"{c.get('engine.step_compiles', 0):.0f}; dropped rows "
+            f"{c.get('moe.dropped_rows', 0):.0f}")
 
 
 def _watch_logits(executor) -> list:
@@ -305,16 +293,16 @@ def main(argv=None) -> int:
         f", jax {jax.__version__}")
     from repro.launch.compile_cache import enable_compile_cache
     log(f"compile cache: {enable_compile_cache()}")
-    compiles = CompileCounter()
+    tracing.watch_compiles()
     t0 = time.perf_counter()
     if args.chips == 4:
         four_chip_phase()
     else:
         serve_phase()
-        log(f"after serve: {compiles.line()}")
+        log(f"after serve: {compiles_line()}")
         gc.collect()                  # one engine's params at a time
         moe_layer_phase()
-    log(f"{compiles.line()}; total {time.perf_counter() - t0:.1f} s")
+    log(f"{compiles_line()}; total {time.perf_counter() - t0:.1f} s")
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

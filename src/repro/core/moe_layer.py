@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.core import comm, dispatch, expert_server, mapping as emap, router
 from repro.core.expert_server import ServerWeights
@@ -128,15 +129,17 @@ def default_runtime(cfg: ModelConfig, num_servers: int,
 
 def _client_extras(params: Dict, x: jax.Array, cfg_moe: MoEConfig,
                    activation: str) -> jax.Array:
-    """Shared experts + dense residual — the client-side dense FFN tier."""
+    """Shared experts + dense residual — the client-side dense FFN tier,
+    traced in the ``combine`` stage."""
     from repro.models.mlp import mlp
 
-    extra = jnp.zeros_like(x)
-    if "shared" in params:
-        extra = extra + mlp(params["shared"], x, activation)
-    if "residual" in params:
-        extra = extra + mlp(params["residual"], x, activation)
-    return extra
+    with tracing.stage("combine"):
+        extra = jnp.zeros_like(x)
+        if "shared" in params:
+            extra = extra + mlp(params["shared"], x, activation)
+        if "residual" in params:
+            extra = extra + mlp(params["residual"], x, activation)
+        return extra
 
 
 def eaas_moe_apply(params: Dict, x: jax.Array, cfg_moe: MoEConfig,
@@ -149,61 +152,68 @@ def eaas_moe_apply(params: Dict, x: jax.Array, cfg_moe: MoEConfig,
     In SPMD modes this must be called inside shard_map with ``axis_name``
     bound to the server mesh axis; params["servers"] arrays then hold only
     the local shard (leading dim 1) and are squeezed here.
+
+    Device work is tagged by stage (:func:`repro.tracing.stage`):
+    ``router``, ``dispatch`` (replica lookup, packing, the send),
+    ``server`` (the expert servers; their grouped GEMM kernel tags itself
+    ``gemm``) and ``combine`` (the return, the weighted sum and the client's
+    dense extras).
     """
     T, d = x.shape
     S, C = runtime.num_servers, runtime.capacity
 
     # ---- client: route + resolve service instances ----------------------
-    r = router.route(params["router"], x, cfg_moe,
-                     bias=runtime.route_bias)
-    if token_salt is None:
-        token_salt = jnp.arange(T, dtype=jnp.int32)[:, None] + jnp.arange(
-            r.expert_ids.shape[1], dtype=jnp.int32)[None, :]
-    server_ids = emap.lookup(runtime.mapping, runtime.alive,
-                             r.expert_ids, token_salt,
-                             weights=runtime.replica_weights)
+    with tracing.stage("router"):
+        r = router.route(params["router"], x, cfg_moe,
+                         bias=runtime.route_bias)
+        expert_load = router.expert_load(r.expert_ids, cfg_moe.num_experts)
+    with tracing.stage("dispatch"):
+        if token_salt is None:
+            token_salt = jnp.arange(T, dtype=jnp.int32)[:, None] + jnp.arange(
+                r.expert_ids.shape[1], dtype=jnp.int32)[None, :]
+        server_ids = emap.lookup(runtime.mapping, runtime.alive,
+                                 r.expert_ids, token_salt,
+                                 weights=runtime.replica_weights)
 
-    # ---- client: pack buffer slots (paper §3.2) --------------------------
-    buffers = dispatch.pack(x, r.expert_ids, r.scores, server_ids, S, C,
-                            method=runtime.dispatch_method)
-
-    # ---- transfer + server compute ---------------------------------------
-    if axis_name is None:
-        sw = params["servers"]
-        # vmap the stateless server over the S logical instances
-        def one_server(wg, wu, wd, tbl, hid, eid, sc, cnt):
-            w = ServerWeights(wg, wu, wd, tbl)
-            out, st = expert_server.serve(hid[None], eid[None], sc[None],
-                                          cnt[None], w,
-                                          impl=runtime.gemm_impl)
-            return out[0], st
-        hid, eid, sc, cnt = comm.send_to_servers(buffers, None, "local")
-        out_slots, st = jax.vmap(one_server)(
-            sw["w_gate"], sw["w_up"], sw["w_down"], runtime.local_table,
-            hid, eid, sc, cnt)
-        result = comm.return_to_clients(out_slots, None, "local")
-        miss = jnp.sum(st.miss)
-    else:
-        sw = params["servers"]
-        w = ServerWeights(sw["w_gate"][0], sw["w_up"][0], sw["w_down"][0],
-                          runtime.local_table[0])
+        # ---- client: pack buffer slots (paper §3.2) ----------------------
+        buffers = dispatch.pack(x, r.expert_ids, r.scores, server_ids, S, C,
+                                method=runtime.dispatch_method)
         hid, eid, sc, cnt = comm.send_to_servers(buffers, axis_name, mode)
-        out_slots, st = expert_server.serve(hid, eid, sc, cnt, w,
-                                            impl=runtime.gemm_impl)
-        result = comm.return_to_clients(out_slots, axis_name, mode)
-        miss = st.miss
+
+    # ---- server compute ----------------------------------------------------
+    sw = params["servers"]
+    with tracing.stage("server"):
+        if axis_name is None:
+            # vmap the stateless server over the S logical instances
+            def one_server(wg, wu, wd, tbl, hid, eid, sc, cnt):
+                w = ServerWeights(wg, wu, wd, tbl)
+                out, st = expert_server.serve(hid[None], eid[None], sc[None],
+                                              cnt[None], w,
+                                              impl=runtime.gemm_impl)
+                return out[0], st
+            out_slots, st = jax.vmap(one_server)(
+                sw["w_gate"], sw["w_up"], sw["w_down"], runtime.local_table,
+                hid, eid, sc, cnt)
+            miss = jnp.sum(st.miss)
+        else:
+            w = ServerWeights(sw["w_gate"][0], sw["w_up"][0],
+                              sw["w_down"][0], runtime.local_table[0])
+            out_slots, st = expert_server.serve(hid, eid, sc, cnt, w,
+                                                impl=runtime.gemm_impl)
+            miss = st.miss
 
     # ---- client: combine (weighted sum arrives pre-weighted) -------------
-    y = dispatch.combine(result, buffers.combine_slot, out_dtype=x.dtype)
-    y = comm.finalize_combine(y, axis_name, mode)
-
-    y = y + _client_extras(params, x, cfg_moe, activation)
+    with tracing.stage("combine"):
+        result = comm.return_to_clients(out_slots, axis_name, mode)
+        y = dispatch.combine(result, buffers.combine_slot, out_dtype=x.dtype)
+        y = comm.finalize_combine(y, axis_name, mode)
+        y = y + _client_extras(params, x, cfg_moe, activation)
 
     stats = MoEStats(
         aux_loss=r.aux_loss,
         z_loss=r.z_loss,
         dropped=buffers.dropped,
         miss=miss,
-        expert_load=router.expert_load(r.expert_ids, cfg_moe.num_experts),
+        expert_load=expert_load,
     )
     return y, stats

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import tracing
 from repro.kernels import group_shrink as gs
 
 
@@ -77,6 +78,8 @@ def grouped_gemm_pallas(x_sorted: jax.Array, w: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="eaas_grouped_gemm",
+        metadata={tracing.STAGE_KEY: "gemm"},
     )(table.tile_gid, table.tile_valid, x_pad, w)
 
     out = gs.unpad_rows(out_pad, padded_idx, row_live)

@@ -32,6 +32,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.kernels import ref as kref
 from repro.kernels.combine import combine_weighted_pallas
 from repro.kernels.decode_attention import (flash_decode_pallas,
@@ -97,22 +98,26 @@ def grouped_gemm_dense(x_sorted: jax.Array, w: jax.Array,
 def grouped_gemm(x_sorted: jax.Array, w: jax.Array, group_sizes: jax.Array,
                  *, impl: str = "auto", expert_capacity: Optional[int] = None,
                  tm: int = 128, tn: int = 128, tk: int = 128) -> jax.Array:
-    """out[i] = x_sorted[i] @ w[g(i)] — see module docstring for impls."""
+    """out[i] = x_sorted[i] @ w[g(i)] — see module docstring for impls.
+    The XLA lowerings are traced in the ``gemm`` stage; the Pallas kernel
+    carries that tag in its own metadata."""
     impl = _resolve("grouped_gemm", impl, "xla_ragged", _GEMM_IMPLS)
-    if impl == "ref":
-        return kref.grouped_gemm_ref(x_sorted, w, group_sizes)
-    if impl == "xla_ragged":
-        y = jax.lax.ragged_dot(x_sorted, w, group_sizes.astype(jnp.int32))
-        # ragged_dot leaves rows past sum(group_sizes) unspecified: mask them
-        live = jnp.arange(x_sorted.shape[0]) < jnp.sum(group_sizes)
-        return jnp.where(live[:, None], y, 0).astype(x_sorted.dtype)
-    if impl == "xla_dense":
+    if impl in ("pallas", "pallas_interpret"):
+        return grouped_gemm_pallas(
+            x_sorted, w, group_sizes, tm=tm, tn=tn, tk=tk,
+            interpret=(impl == "pallas_interpret"))
+    with tracing.stage("gemm"):
+        if impl == "ref":
+            return kref.grouped_gemm_ref(x_sorted, w, group_sizes)
+        if impl == "xla_ragged":
+            y = jax.lax.ragged_dot(x_sorted, w,
+                                   group_sizes.astype(jnp.int32))
+            # ragged_dot leaves rows past sum(group_sizes) unspecified
+            live = jnp.arange(x_sorted.shape[0]) < jnp.sum(group_sizes)
+            return jnp.where(live[:, None], y, 0).astype(x_sorted.dtype)
         M, G = x_sorted.shape[0], w.shape[0]
         cap = expert_capacity or max(_ceil_mult(2 * M // max(G, 1) + 1, 8), 8)
         return grouped_gemm_dense(x_sorted, w, group_sizes, cap)
-    return grouped_gemm_pallas(
-        x_sorted, w, group_sizes, tm=tm, tn=tn, tk=tk,
-        interpret=(impl == "pallas_interpret"))
 
 
 def _ceil_mult(x: int, m: int) -> int:

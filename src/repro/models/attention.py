@@ -5,6 +5,9 @@ Pure per-shard math; distribution (TP over heads, DP over batch, SP over the
 cache for long contexts) is applied by the launch layer via shardings.
 RoPE is applied to q/k *before* the keys are cached, so cached keys are
 already rotated (standard practice; makes ring-buffer windows trivial).
+What the public functions here trace (projections, rope, the cache write,
+the attention itself and the output projection) carries the ``attention``
+stage tag (:func:`repro.tracing.stage`).
 """
 
 from __future__ import annotations
@@ -15,6 +18,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.models import kv_cache as kvc
 from repro.models.common import dense_init, softcap
@@ -90,28 +94,30 @@ def full_attention(params: Dict, cfg: ModelConfig, x: jax.Array,
     Long sequences run the chunked path: a scan over q blocks, each block
     rematerialized in backward (flash-attention memory profile).
     """
-    b, s, d = x.shape
-    h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = _split_heads(x @ params["wq"], h, hd)
-    k = _split_heads(x @ params["wk"], kvh, hd)
-    v = _split_heads(x @ params["wv"], kvh, hd)
+    with tracing.stage("attention"):
+        b, s, d = x.shape
+        h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        q = _split_heads(x @ params["wq"], h, hd)
+        k = _split_heads(x @ params["wk"], kvh, hd)
+        v = _split_heads(x @ params["wv"], kvh, hd)
 
-    if positions.ndim == 1:
-        positions = jnp.broadcast_to(positions[None], (b, s))
-    cos, sin = _rope_for(cfg, positions, mrope_positions)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+        if positions.ndim == 1:
+            positions = jnp.broadcast_to(positions[None], (b, s))
+        cos, sin = _rope_for(cfg, positions, mrope_positions)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    if s >= CHUNKED_ATTN_THRESHOLD and s % q_blocks == 0:
-        out = _chunked_core(cfg, q, k, v, positions, is_local=is_local,
-                            causal=causal, q_blocks=q_blocks, unroll=unroll)
-    else:
-        out = _dense_core(cfg, q, k, v, positions, positions,
-                          is_local=is_local, causal=causal)
-    out = out.reshape(b, s, h * hd).astype(x.dtype) @ params["wo"]
-    if return_kv:
-        return out, (k, v)
-    return out
+        if s >= CHUNKED_ATTN_THRESHOLD and s % q_blocks == 0:
+            out = _chunked_core(cfg, q, k, v, positions,
+                                is_local=is_local, causal=causal,
+                                q_blocks=q_blocks, unroll=unroll)
+        else:
+            out = _dense_core(cfg, q, k, v, positions, positions,
+                              is_local=is_local, causal=causal)
+        out = out.reshape(b, s, h * hd).astype(x.dtype) @ params["wo"]
+        if return_kv:
+            return out, (k, v)
+        return out
 
 
 def _dense_core(cfg, q, k, v, q_pos, k_pos, *, is_local, causal):
@@ -171,30 +177,31 @@ def chunk_attention(params: Dict, cfg: ModelConfig, x: jax.Array,
     prefix-cache blocks written by an *earlier* request, which is how a
     prefix hit lets the chunk start mid-prompt.
     """
-    b, s, d = x.shape
-    h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = _split_heads(x @ params["wq"], h, hd)
-    k = _split_heads(x @ params["wk"], kvh, hd)
-    v = _split_heads(x @ params["wv"], kvh, hd)
+    with tracing.stage("attention"):
+        b, s, d = x.shape
+        h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        q = _split_heads(x @ params["wq"], h, hd)
+        k = _split_heads(x @ params["wk"], kvh, hd)
+        v = _split_heads(x @ params["wv"], kvh, hd)
 
-    pos_b = jnp.broadcast_to(positions[None], (b, s))
-    cos, sin = _rope_for(cfg, pos_b, mrope_positions)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+        pos_b = jnp.broadcast_to(positions[None], (b, s))
+        cos, sin = _rope_for(cfg, pos_b, mrope_positions)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    if isinstance(cache, kvc.PagedKVCache):
-        cache = kvc.paged_write_chunk(cache, k, v, positions[0])
-        ck, cv = kvc.gather_blocks(cache)
-    else:
-        cache = kvc.write_chunk(cache, k, v, positions[0])
-        ck, cv = cache.k, cache.v
-    slots = ck.shape[1]
-    k_pos = jnp.broadcast_to(jnp.arange(slots, dtype=jnp.int32)[None],
-                             (b, slots))
-    out = _dense_core(cfg, q, ck, cv, pos_b, k_pos,
-                      is_local=False, causal=True)
-    out = out.reshape(b, s, h * hd).astype(x.dtype) @ params["wo"]
-    return out, cache
+        if isinstance(cache, kvc.PagedKVCache):
+            cache = kvc.paged_write_chunk(cache, k, v, positions[0])
+            ck, cv = kvc.gather_blocks(cache)
+        else:
+            cache = kvc.write_chunk(cache, k, v, positions[0])
+            ck, cv = cache.k, cache.v
+        slots = ck.shape[1]
+        k_pos = jnp.broadcast_to(jnp.arange(slots, dtype=jnp.int32)[None],
+                                 (b, slots))
+        out = _dense_core(cfg, q, ck, cv, pos_b, k_pos,
+                          is_local=False, causal=True)
+        out = out.reshape(b, s, h * hd).astype(x.dtype) @ params["wo"]
+        return out, cache
 
 
 # ------------------------------------------------------------------- decode
@@ -213,32 +220,33 @@ def decode_attention(params: Dict, cfg: ModelConfig, x: jax.Array,
 
     Returns (output (batch, 1, d_model), updated cache).
     """
-    b = x.shape[0]
-    h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = _split_heads(x @ params["wq"], h, hd)        # (b,1,H,hd)
-    k = _split_heads(x @ params["wk"], kvh, hd)
-    v = _split_heads(x @ params["wv"], kvh, hd)
+    with tracing.stage("attention"):
+        b = x.shape[0]
+        h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        q = _split_heads(x @ params["wq"], h, hd)        # (b,1,H,hd)
+        k = _split_heads(x @ params["wk"], kvh, hd)
+        v = _split_heads(x @ params["wv"], kvh, hd)
 
-    positions = cache.length[:, None]                # (b,1) current position
-    cos, sin = _rope_for(cfg, positions, mrope_positions)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+        positions = cache.length[:, None]            # (b,1) current position
+        cos, sin = _rope_for(cfg, positions, mrope_positions)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    if isinstance(cache, kvc.PagedKVCache):
-        cache = kvc.paged_append_decode(cache, k, v)
-        ck, cv = kvc.gather_blocks(cache)
-        mask = kvc.paged_valid_mask(cache)[:, None, None, None, :]
-    else:
-        cache = kvc.append_decode(cache, k, v)
-        ck, cv = cache.k, cache.v
-        mask = kvc.valid_mask(cache)[:, None, None, None, :]
-    scores = _gqa_scores(q, ck) / math.sqrt(hd)        # (b,KV,G,1,slots)
-    if cfg.attn_logit_softcap:
-        scores = softcap(scores, cfg.attn_logit_softcap)
-    scores = jnp.where(mask, scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    out = _gqa_out(p, cv).astype(x.dtype)
-    return out.reshape(b, 1, h * hd) @ params["wo"], cache
+        if isinstance(cache, kvc.PagedKVCache):
+            cache = kvc.paged_append_decode(cache, k, v)
+            ck, cv = kvc.gather_blocks(cache)
+            mask = kvc.paged_valid_mask(cache)[:, None, None, None, :]
+        else:
+            cache = kvc.append_decode(cache, k, v)
+            ck, cv = cache.k, cache.v
+            mask = kvc.valid_mask(cache)[:, None, None, None, :]
+        scores = _gqa_scores(q, ck) / math.sqrt(hd)        # (b,KV,G,1,slots)
+        if cfg.attn_logit_softcap:
+            scores = softcap(scores, cfg.attn_logit_softcap)
+        scores = jnp.where(mask, scores, NEG_INF)
+        p = jax.nn.softmax(scores, axis=-1)
+        out = _gqa_out(p, cv).astype(x.dtype)
+        return out.reshape(b, 1, h * hd) @ params["wo"], cache
 
 
 def decode_attention_partial(params: Dict, cfg: ModelConfig, q: jax.Array,
@@ -250,18 +258,19 @@ def decode_attention_partial(params: Dict, cfg: ModelConfig, q: jax.Array,
     be combined with a small cross-shard softmax reduction:
         acc: (b, 1, H, hd) unnormalized sum of p*v, m: (b,1,H,1), l: (b,1,H,1)
     """
-    hd = cfg.head_dim
-    scores = _gqa_scores(q, cache.k) / math.sqrt(hd)   # (b,KV,G,1,slots)
-    mask = kvc.valid_mask(cache)[:, None, None, None, :]
-    scores = jnp.where(mask, scores, NEG_INF)
-    m = jnp.max(scores, axis=-1, keepdims=True)
-    p = jnp.exp(scores - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    acc = _gqa_out(p, cache.v)                         # (b,1,H,hd) fp32
-    b, _, h, _ = q.shape
-    m = m.reshape(b, 1, h, 1)
-    l = l.reshape(b, 1, h, 1)
-    return acc, m, l
+    with tracing.stage("attention"):
+        hd = cfg.head_dim
+        scores = _gqa_scores(q, cache.k) / math.sqrt(hd)   # (b,KV,G,1,slots)
+        mask = kvc.valid_mask(cache)[:, None, None, None, :]
+        scores = jnp.where(mask, scores, NEG_INF)
+        m = jnp.max(scores, axis=-1, keepdims=True)
+        p = jnp.exp(scores - m)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        acc = _gqa_out(p, cache.v)                         # (b,1,H,hd) fp32
+        b, _, h, _ = q.shape
+        m = m.reshape(b, 1, h, 1)
+        l = l.reshape(b, 1, h, 1)
+        return acc, m, l
 
 
 def combine_partial_attention(acc, m, l, axis_name: str):
@@ -282,20 +291,22 @@ def init_cross_attention(key, cfg: ModelConfig) -> Dict:
 def cross_attention(params: Dict, cfg: ModelConfig, x: jax.Array,
                     enc_out: jax.Array) -> jax.Array:
     """Decoder cross-attention (whisper): queries from x, k/v from enc_out."""
-    b, s, d = x.shape
-    h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    k = _split_heads(enc_out @ params["wk"], kvh, hd)
-    v = _split_heads(enc_out @ params["wv"], kvh, hd)
-    return cross_attention_cached(params, cfg, x, k, v)
+    with tracing.stage("attention"):
+        b, s, d = x.shape
+        h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        k = _split_heads(enc_out @ params["wk"], kvh, hd)
+        v = _split_heads(enc_out @ params["wv"], kvh, hd)
+        return cross_attention_cached(params, cfg, x, k, v)
 
 
 def cross_attention_cached(params: Dict, cfg: ModelConfig, x: jax.Array,
                            k: jax.Array, v: jax.Array) -> jax.Array:
     """Cross-attention against precomputed encoder K/V (decode path)."""
-    b, s, d = x.shape
-    h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = _split_heads(x @ params["wq"], h, hd)
-    scores = _gqa_scores(q, k) / math.sqrt(hd)
-    p = jax.nn.softmax(scores, axis=-1)
-    out = _gqa_out(p, v).astype(x.dtype)
-    return out.reshape(b, s, h * hd) @ params["wo"]
+    with tracing.stage("attention"):
+        b, s, d = x.shape
+        h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        q = _split_heads(x @ params["wq"], h, hd)
+        scores = _gqa_scores(q, k) / math.sqrt(hd)
+        p = jax.nn.softmax(scores, axis=-1)
+        out = _gqa_out(p, v).astype(x.dtype)
+        return out.reshape(b, s, h * hd) @ params["wo"]

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.core import moe_layer as eaas
 from repro.core.moe_layer import MoERuntime, MoEStats
@@ -491,6 +492,15 @@ def _logits(params: Dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     return logits
 
 
+def _head(params: Dict, cfg: ModelConfig, x: jax.Array, at: int
+          ) -> jax.Array:
+    """The served head, in the ``head`` stage: the final norm, then fp32
+    logits at sequence position ``at``."""
+    with tracing.stage("head"):
+        x = rms_norm(x, params["final_ln"], cfg.rms_norm_eps)
+        return _logits(params, cfg, x[:, at]).astype(jnp.float32)
+
+
 def chunked_cross_entropy(params: Dict, cfg: ModelConfig, x: jax.Array,
                           labels: jax.Array, ctx: ParallelCtx) -> jax.Array:
     """CE without materializing (B, S, V) logits: scan + remat over seq
@@ -664,8 +674,7 @@ def _build_decoder(cfg: ModelConfig, num_servers: int,
         x, cb, _ = _scan_prefill(params["blocks"], cache["blocks"], cfg, x,
                                  pos, ctx, mrope=mrope)
         cache["blocks"] = cb
-        x = rms_norm(x, params["final_ln"], cfg.rms_norm_eps)
-        logits = _logits(params, cfg, x[:, -1]).astype(jnp.float32)
+        logits = _head(params, cfg, x, -1)
         return logits, cache
 
     def prefill_chunk(params, tokens, cache, start, ctx: ParallelCtx,
@@ -699,8 +708,7 @@ def _build_decoder(cfg: ModelConfig, num_servers: int,
                                         cfg, x, pos, ctx, mrope=mrope)
         cache = dict(cache, blocks=cb)
         stats_all.append(st)
-        x = rms_norm(x, params["final_ln"], cfg.rms_norm_eps)
-        logits = _logits(params, cfg, x[:, -1]).astype(jnp.float32)
+        logits = _head(params, cfg, x, -1)
         return logits, cache, _sum_stats(*stats_all)
 
     def decode_step(params, token, cache, ctx: ParallelCtx, batch=None):
@@ -714,8 +722,7 @@ def _build_decoder(cfg: ModelConfig, num_servers: int,
         x, cb, st = _scan_decode(params["blocks"], cache["blocks"], cfg, x, ctx)
         cache = dict(cache, blocks=cb)
         stats_all.append(st)
-        x = rms_norm(x, params["final_ln"], cfg.rms_norm_eps)
-        logits = _logits(params, cfg, x[:, 0]).astype(jnp.float32)
+        logits = _head(params, cfg, x, 0)
         return logits, cache, _sum_stats(*stats_all)
 
     return Model(cfg, init_params, loss_fn, prefill, decode_step, init_cache,
@@ -852,8 +859,7 @@ def _build_local_global(cfg: ModelConfig) -> Model:
             x, cr, _ = _scan_prefill(params["rem_local"], cache["rem"], cfg,
                                      x, pos, ctx, is_local=True)
             cache["rem"] = cr
-        x = rms_norm(x, params["final_ln"], cfg.rms_norm_eps)
-        return _logits(params, cfg, x[:, -1]).astype(jnp.float32), cache
+        return _head(params, cfg, x, -1), cache
 
     def decode_step(params, token, cache, ctx: ParallelCtx, batch=None):
         x = _embed_tokens(params, cfg, token, ctx)
@@ -873,8 +879,7 @@ def _build_local_global(cfg: ModelConfig) -> Model:
             x, cr, _ = _scan_decode(params["rem_local"], cache["rem"], cfg,
                                     x, ctx, is_local=True)
             cache["rem"] = cr
-        x = rms_norm(x, params["final_ln"], cfg.rms_norm_eps)
-        logits = _logits(params, cfg, x[:, 0]).astype(jnp.float32)
+        logits = _head(params, cfg, x, 0)
         return logits, cache, None
 
     return Model(cfg, init_params, loss_fn, prefill, decode_step, init_cache, 1)
@@ -991,8 +996,7 @@ def _build_zamba(cfg: ModelConfig) -> Model:
             group_body, x, (params["mamba"], cache["mamba"], cache["shared"]),
             unroll=ctx.unroll_scans)
         cache = {"mamba": nst, "shared": ckv}
-        x = rms_norm(x, params["final_ln"], cfg.rms_norm_eps)
-        return _logits(params, cfg, x[:, -1]).astype(jnp.float32), cache
+        return _head(params, cfg, x, -1), cache
 
     def decode_step(params, token, cache, ctx: ParallelCtx, batch=None):
         x = _embed_tokens(params, cfg, token, ctx)
@@ -1007,8 +1011,7 @@ def _build_zamba(cfg: ModelConfig) -> Model:
             group_body, x, (params["mamba"], cache["mamba"], cache["shared"]),
             unroll=ctx.unroll_scans)
         cache = {"mamba": nst, "shared": ckv}
-        x = rms_norm(x, params["final_ln"], cfg.rms_norm_eps)
-        logits = _logits(params, cfg, x[:, 0]).astype(jnp.float32)
+        logits = _head(params, cfg, x, 0)
         return logits, cache, None
 
     return Model(cfg, init_params, loss_fn, prefill, decode_step, init_cache, 1)
@@ -1101,9 +1104,7 @@ def _build_rwkv(cfg: ModelConfig) -> Model:
         B, S = tokens.shape
         x = _embed_tokens(params, cfg, tokens, ctx)
         x, nst = _forward(params, x, _states(B, False, ctx), ctx)
-        x = rms_norm(x, params["final_ln"], cfg.rms_norm_eps)
-        return (_logits(params, cfg, x[:, -1]).astype(jnp.float32),
-                {"states": nst})
+        return _head(params, cfg, x, -1), {"states": nst}
 
     def decode_step(params, token, cache, ctx: ParallelCtx, batch=None):
         x = _embed_tokens(params, cfg, token, ctx)
@@ -1115,8 +1116,7 @@ def _build_rwkv(cfg: ModelConfig) -> Model:
             return xc, nst
         x, nst = jax.lax.scan(body, x, (params["blocks"], cache["states"]),
                               unroll=ctx.unroll_scans)
-        x = rms_norm(x, params["final_ln"], cfg.rms_norm_eps)
-        logits = _logits(params, cfg, x[:, 0]).astype(jnp.float32)
+        logits = _head(params, cfg, x, 0)
         return logits, {"states": nst}, None
 
     return Model(cfg, init_params, loss_fn, prefill, decode_step, init_cache, 1)
@@ -1237,8 +1237,7 @@ def _build_encdec(cfg: ModelConfig) -> Model:
             body, x, (params["decoder"], cache["self"]),
             unroll=ctx.unroll_scans)
         cache = {"self": self_kv, "cross_k": ck, "cross_v": cv}
-        x = rms_norm(x, params["final_ln"], cfg.rms_norm_eps)
-        return _logits(params, cfg, x[:, -1]).astype(jnp.float32), cache
+        return _head(params, cfg, x, -1), cache
 
     def decode_step(params, token, cache, ctx: ParallelCtx, batch=None):
         x = _embed_tokens(params, cfg, token, ctx)
@@ -1258,8 +1257,7 @@ def _build_encdec(cfg: ModelConfig) -> Model:
             body, x, (params["decoder"], cache["self"], cache["cross_k"],
                       cache["cross_v"]), unroll=ctx.unroll_scans)
         cache = dict(cache, self=self_kv)
-        x = rms_norm(x, params["final_ln"], cfg.rms_norm_eps)
-        logits = _logits(params, cfg, x[:, 0]).astype(jnp.float32)
+        logits = _head(params, cfg, x, 0)
         return logits, cache, None
 
     return Model(cfg, init_params, loss_fn, prefill, decode_step, init_cache, 1)

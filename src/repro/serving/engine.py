@@ -64,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import SingleDeviceSharding
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.core.elastic import ServerPool
 from repro.core.load_balance import lane_loads, server_loads
@@ -214,6 +215,7 @@ class ServingEngine:
     def __init__(self, cfg: ModelConfig, engine_cfg: EngineConfig,
                  params=None, seed: int = 0, clock: Optional[Clock] = None,
                  pool=None, client_id: int = 0, tier=None):
+        tracing.watch_compiles()
         self.cfg = cfg
         self.ecfg = engine_cfg
         self.client_id = client_id
@@ -689,31 +691,59 @@ class ServingEngine:
     # ---------------------------------------------------------------- step
     def step(self) -> None:
         """One engine iteration: run whatever the scheduler plans next —
-        a prefill chunk, a decode step over the ready slots, or idle."""
+        a prefill chunk, a decode step over the ready slots, or idle.  It
+        is the ``engine.step`` span (:mod:`repro.tracing`), numbered by
+        ``step_idx``, its ``kind`` the step it ran."""
         self.step_idx += 1
-        if self.step_idx <= self.halted_until:
-            # monolithic restart: time passes, no tokens are produced
-            self.clock += self._last_decode_time
-            self.metrics.timeline.append(
-                {"t": self.clock, "tokens": 0, "halted": True})
-            return
-        if self.ecfg.exec_mode == "async":
-            self._step_async()
-        else:
-            plan = self.scheduler.next_plan()
-            if isinstance(plan, PrefillChunk):
-                self._step_prefill(plan)
-            elif isinstance(plan, DecodeBatch):
-                self._step_decode(plan)
+        with tracing.step("engine.step", self.step_idx) as span:
+            if self.step_idx <= self.halted_until:
+                # monolithic restart: time passes, no tokens are produced
+                span.set(kind="idle")
+                self.clock += self._last_decode_time
+                self.metrics.timeline.append(
+                    {"t": self.clock, "tokens": 0, "halted": True})
+                return
+            if self.ecfg.exec_mode == "async":
+                kind = self._step_async()
             else:
-                self.clock += self.clk.idle()
-        if self.rebalancer is not None:
-            # migration chunks interleave with decode steps — serving
-            # never pauses for a replan (paper §4.5 live adaptation)
-            self.rebalancer.step(self)
-        if self.kv_pool is not None:
-            self.metrics.observe_kv(self.kv_pool,
-                                    self.scheduler.preemptions)
+                plan = self.scheduler.next_plan()
+                kind = "idle"
+                if isinstance(plan, PrefillChunk):
+                    kind = "prefill"
+                    self._step_prefill(plan)
+                elif isinstance(plan, DecodeBatch):
+                    kind = "decode"
+                    self._step_decode(plan)
+                else:
+                    self.clock += self.clk.idle()
+            span.set(kind=kind)
+            if self.rebalancer is not None:
+                # migration chunks interleave with decode steps — serving
+                # never pauses for a replan (paper §4.5 live adaptation)
+                self.rebalancer.step(self)
+            if self.kv_pool is not None:
+                self.metrics.observe_kv(self.kv_pool,
+                                        self.scheduler.preemptions)
+
+    def _sample(self, logits, temps: np.ndarray,
+                steps: np.ndarray) -> np.ndarray:
+        """Every slot's next token, on the host (``executor.sample``; the
+        pull of the tokens is ``executor.wait``)."""
+        with tracing.span("executor.sample"):
+            tokens = sample_batch(logits, temps, self.scheduler.slot_keys,
+                                  steps)
+            with tracing.span("executor.wait"):
+                return np.asarray(tokens)
+
+    def _sample_first(self, logits, req: Request, b: int) -> int:
+        """The first token of ``req`` in slot ``b``, from its prefill's
+        logits: the per-slot key the decode path uses (stored at
+        admission), folded with token index 0 — one key-derivation site."""
+        key = jnp.asarray(self.scheduler.slot_keys[b])
+        token = sample(logits, req.sampling.temperature,
+                       jax.random.fold_in(key, 0))
+        with tracing.span("executor.wait"):
+            return int(token[0])
 
     def _step_prefill(self, plan: PrefillChunk) -> None:
         req, b = plan.request, plan.slot
@@ -735,26 +765,23 @@ class ServingEngine:
             logits, expert_load = self.executor.prefill_chunk(
                 b, chunk, plan.start,
                 is_first=plan.is_first, is_last=plan.is_last)
-        if (expert_load is not None and self.pool is not None
-                and self.ecfg.prefill_load_feedback):
+        load = self.executor.pull_load(expert_load)
+        if load is not None and self.ecfg.prefill_load_feedback:
             # chunked-prefill router traffic feeds the same EMA decode
             # feeds — prompt-heavy workloads rebalance from prompt traffic
-            self.pool.observe_load(np.asarray(expert_load))
-        self.clock += self.clk.stop("prefill", result=logits,
-                                    tokens=plan.length,
-                                    servers=self._pool_size(),
-                                    alive_frac=self._alive_frac())
-        if expert_load is not None:
-            self.clock += self._charge_cold_starts(expert_load)
+            self.pool.observe_load(load)
+        with tracing.span("executor.wait"):
+            self.clock += self.clk.stop("prefill", result=logits,
+                                        tokens=plan.length,
+                                        servers=self._pool_size(),
+                                        alive_frac=self._alive_frac())
+        if load is not None:
+            self.clock += self._charge_cold_starts(load)
         self.scheduler.prefill_advanced(b, plan.length)
         if plan.is_last and not req.output_tokens:
-            # same per-slot key the decode path uses (stored at admission),
-            # folded with token index 0 — one key-derivation site.  A
-            # *resumed* (preempted) request already holds its next input
-            # token, so recompute prefills skip sampling and TTFT.
-            key = jnp.asarray(self.scheduler.slot_keys[b])
-            first = int(sample(logits, req.sampling.temperature,
-                               jax.random.fold_in(key, 0))[0])
+            # a *resumed* (preempted) request already holds its next input
+            # token, so recompute prefills skip sampling and TTFT
+            first = self._sample_first(logits, req, b)
             req.output_tokens.append(first)
             req.prefill_time = self.clock
             self.metrics.ttfts.append(self.clock - req.arrival_time)
@@ -782,29 +809,30 @@ class ServingEngine:
         else:
             logits, expert_load = self.executor.decode(tokens)
         imbalance = 1.0
-        if self.pool is not None:
+        load = self.executor.pull_load(expert_load)
+        if load is not None:
             # fold this step's router traffic into the EMA first, so the
             # imbalance charged (and surfaced) reflects current traffic;
             # the gauge itself is only computed when something consumes it
             # (cost model or controller) — it walks the mapping in Python
-            self.pool.observe_load(np.asarray(expert_load))
+            self.pool.observe_load(load)
             if self.ecfg.charge_imbalance or self.track_imbalance:
                 imbalance = self.pool.current_imbalance()
                 self.metrics.observe_balance(imbalance)
-        dt = self.clk.stop("decode", result=logits, tokens=len(active),
-                           servers=self._pool_size(),
-                           alive_frac=self._alive_frac(),
-                           overlap=(self.ecfg.decode_mode == "pipelined"),
-                           imbalance=(imbalance
-                                      if self.ecfg.charge_imbalance
-                                      else 1.0),
-                           contention=self.expert_contention,
-                           straggle=self._straggle())
+        with tracing.span("executor.wait"):
+            dt = self.clk.stop("decode", result=logits, tokens=len(active),
+                               servers=self._pool_size(),
+                               alive_frac=self._alive_frac(),
+                               overlap=(self.ecfg.decode_mode == "pipelined"),
+                               imbalance=(imbalance
+                                          if self.ecfg.charge_imbalance
+                                          else 1.0),
+                               contention=self.expert_contention,
+                               straggle=self._straggle())
         self._last_decode_time = dt
         self.clock += dt
-        self.clock += self._charge_cold_starts(expert_load)
-        next_tokens = np.asarray(sample_batch(logits, temps,
-                                              sch.slot_keys, steps))
+        self.clock += self._charge_cold_starts(load)
+        next_tokens = self._sample(logits, temps, steps)
 
         produced = 0
         for b in active:
@@ -828,8 +856,9 @@ class ServingEngine:
             {"t": self.clock, "tokens": produced, "halted": False})
 
     # --------------------------------------------------------- async steps
-    def _step_async(self) -> None:
-        """One event-driven iteration.
+    def _step_async(self) -> str:
+        """One event-driven iteration; returns what it ran (``prefill``,
+        ``decode`` or ``idle``: an event handled, or time passed).
 
         If the attention client is free, plan eagerly: a prefill chunk
         runs now (overlapping any in-flight wave's expert phase), and a
@@ -845,11 +874,11 @@ class ServingEngine:
             plan = self.scheduler.next_plan()
             if isinstance(plan, PrefillChunk):
                 self._async_prefill(plan)
-                return
+                return "prefill"
             if (isinstance(plan, DecodeBatch)
                     and len(self._waves) < self.ecfg.async_depth
                     and self._async_decode(plan)):
-                return
+                return "decode"
         ev_t = self.timeline.peek_time()
         free_t = (self._client_free_at
                   if self._client_free_at > self.clock else None)
@@ -861,6 +890,7 @@ class ServingEngine:
             self.clock = free_t
         else:
             self.clock += self.clk.idle()
+        return "idle"
 
     def _async_prefill(self, plan: PrefillChunk) -> None:
         """Run one prefill chunk eagerly; its completion (scheduler
@@ -878,19 +908,18 @@ class ServingEngine:
             logits, expert_load = self.executor.prefill_chunk(
                 b, chunk, plan.start,
                 is_first=plan.is_first, is_last=plan.is_last)
-        if (expert_load is not None and self.pool is not None
-                and self.ecfg.prefill_load_feedback):
-            self.pool.observe_load(np.asarray(expert_load))
-        dt = self.clk.stop("prefill", result=logits, tokens=plan.length,
-                           servers=self._pool_size(),
-                           alive_frac=self._alive_frac())
-        if expert_load is not None:
-            dt += self._charge_cold_starts(expert_load)
+        load = self.executor.pull_load(expert_load)
+        if load is not None and self.ecfg.prefill_load_feedback:
+            self.pool.observe_load(load)
+        with tracing.span("executor.wait"):
+            dt = self.clk.stop("prefill", result=logits, tokens=plan.length,
+                               servers=self._pool_size(),
+                               alive_frac=self._alive_frac())
+        if load is not None:
+            dt += self._charge_cold_starts(load)
         first = None
         if plan.is_last and not req.output_tokens:
-            key = jnp.asarray(self.scheduler.slot_keys[b])
-            first = int(sample(logits, req.sampling.temperature,
-                               jax.random.fold_in(key, 0))[0])
+            first = self._sample_first(logits, req, b)
         t_done = self.clock + dt
         self._client_free_at = t_done
         self.timeline.post(t_done, "prefill_done", slot=b,
@@ -963,25 +992,25 @@ class ServingEngine:
             mask[b] = True
         self.clk.start()
         logits, expert_load = self.executor.decode_masked(tokens, mask)
-        if self.pool is not None:
-            self.pool.observe_load(np.asarray(expert_load))
+        load = self.executor.pull_load(expert_load)
+        if load is not None:
+            self.pool.observe_load(load)
             if self.ecfg.charge_imbalance or self.track_imbalance:
                 self.metrics.observe_balance(self.pool.current_imbalance())
-        next_tokens = np.asarray(sample_batch(logits, temps,
-                                              sch.slot_keys, steps))
+        next_tokens = self._sample(logits, temps, steps)
         S = self._pool_size()
         af = self._alive_frac()
         client_dt, expert_dt = self.clk.decode_split(
             tokens=len(active), servers=S, alive_frac=af)
         # a wave routing to paged-out experts stalls its own dispatch on
         # the page-in: the client share absorbs the cold-start penalty
-        client_dt += self._charge_cold_starts(expert_load)
+        client_dt += self._charge_cold_starts(load)
         t_dispatch = self.clock + client_dt
         self._client_free_at = t_dispatch
         wave_id = self._wave_counter
         self._wave_counter += 1
         entries = self._wave_lane_entries(
-            np.asarray(expert_load, np.float64), S, expert_dt)
+            np.asarray(load, np.float64), S, expert_dt)
         wave = {"id": wave_id, "slots": active, "slot_set": set(active),
                 "tokens": next_tokens, "pending": set()}
         self._waves.append(wave)

@@ -29,17 +29,25 @@ The expert→server mapping, liveness mask and local placement table remain
 jit *arguments*: failover and rebalancing never recompile.  A pool resize
 (:meth:`resize`) re-shards the expert weights and rebuilds the jits for the
 new static server count — the AOT-per-server-count story.
+
+Each step method is a host span of its own name (``executor.decode``,
+``executor.prefill_chunk``, ``executor.prefill``; :mod:`repro.tracing`);
+building the placement arrays is ``executor.runtime``.  The MoE step
+programs also return the rows their dispatch buffers dropped, summed over
+layers; :meth:`Executor.pull_load` brings that count to the host with the
+step's expert load, in one transfer, and adds it to ``moe.dropped_rows``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import expert_server
 from repro.core.overlap import split_batch_decode
 from repro.models import kv_cache as kvc
@@ -95,6 +103,7 @@ class Executor:
         else:
             self.cache = model.init_cache(max_batch, max_seq)
         self._staging: Dict[int, object] = {}     # slot -> batch-1 cache
+        self._dropped = None          # rows dropped by the last MoE step
         self._rt0 = pool.runtime(gemm_impl) if pool else None
         self._build_jits()
 
@@ -142,7 +151,7 @@ class Executor:
                     enabled=(self.decode_mode == "pipelined"))
             # per-expert token counts feed the pool's traffic EMA — this is
             # what rebalance() and traffic-aware scale_to re-plan from
-            return logits, cache, st.expert_load
+            return logits, cache, st.expert_load, st.dropped
 
         self._jit_prefill = jax.jit(prefill_fn)
         self._jit_decode = jax.jit(decode_fn)
@@ -166,8 +175,8 @@ class Executor:
                     shape[axis] = mask.shape[0]
                     return jnp.where(mask.reshape(shape), new, old)
 
-                return logits, jax.tree.map(keep, new_cache, cache), \
-                    st.expert_load
+                return (logits, jax.tree.map(keep, new_cache, cache),
+                        st.expert_load, st.dropped)
             self._jit_masked = jax.jit(masked_decode_fn)
         self._jit_chunk = None
         if model.prefill_chunk is not None:
@@ -176,7 +185,7 @@ class Executor:
                     params, tokens, cache, start, ctx_of(rt_arrays))
                 # chunked prefill feeds the traffic EMA like decode does —
                 # the prompt-heavy-workload rebalance signal
-                return logits, cache, st.expert_load
+                return logits, cache, st.expert_load, st.dropped
             self._jit_chunk = jax.jit(chunk_fn)
 
         if self.kv_mode == "paged":
@@ -187,14 +196,14 @@ class Executor:
                 cache = _with_tables(cache, tables, lengths)
                 logits, cache, st = decode_step(params, tokens, cache,
                                                 rt_arrays)
-                return logits, cache, st.expert_load
+                return logits, cache, st.expert_load, st.dropped
 
             def paged_chunk_fn(params, tokens, cache, row, start, rt_arrays):
                 view = _with_tables(cache, row[None],
                                     jnp.broadcast_to(start, (1,)))
                 logits, view, st = model.prefill_chunk(
                     params, tokens, view, start, ctx_of(rt_arrays))
-                return logits, view, st.expert_load
+                return logits, view, st.expert_load, st.dropped
 
             def copy_fn(cache, src, dst):
                 return {k: kvc.copy_blocks(st, src, dst, stacked=True)
@@ -207,20 +216,35 @@ class Executor:
     def _rt_arrays(self):
         if self.pool is None:
             return ()
-        rt = self.pool.runtime(self.gemm_impl)
-        return (rt.mapping, rt.alive, rt.local_table, rt.route_bias,
-                rt.replica_weights)
+        with tracing.span("executor.runtime"):
+            rt = self.pool.runtime(self.gemm_impl)
+            return (rt.mapping, rt.alive, rt.local_table, rt.route_bias,
+                    rt.replica_weights)
+
+    def pull_load(self, expert_load) -> Optional[np.ndarray]:
+        """The step's expert load on the host, fetched in one transfer with
+        the rows its dispatch dropped, which add to ``moe.dropped_rows``;
+        None without an expert tier or a load.  This waits for the step
+        (``executor.wait``)."""
+        if expert_load is None or self.pool is None:
+            return None
+        with tracing.span("executor.wait"):
+            load, dropped = jax.device_get((expert_load, self._dropped))
+        if dropped is not None:
+            tracing.count("moe.dropped_rows", int(dropped))
+        return np.asarray(load)
 
     # ------------------------------------------------------------ prefill
     def prefill(self, slot: int, prompt: np.ndarray) -> jax.Array:
         """Whole-prompt prefill straight into ``slot`` of the batch cache."""
-        tokens = jnp.asarray(prompt, jnp.int32)[None]
-        logits, cache_one = self._jit_prefill(self.params, tokens,
-                                              self._rt_arrays())
-        self.cache = jax.tree.map(
-            lambda big, one: _slot_write(big, one, slot),
-            self.cache, cache_one)
-        return logits
+        with tracing.span("executor.prefill"):
+            tokens = jnp.asarray(prompt, jnp.int32)[None]
+            logits, cache_one = self._jit_prefill(self.params, tokens,
+                                                  self._rt_arrays())
+            self.cache = jax.tree.map(
+                lambda big, one: _slot_write(big, one, slot),
+                self.cache, cache_one)
+            return logits
 
     def prefill_chunk(self, slot: int, chunk: np.ndarray, start: int,
                       *, is_first: bool, is_last: bool
@@ -233,25 +257,28 @@ class Executor:
         commits the staging cache into the batch cache slot.
         """
         assert self._jit_chunk is not None, "model has no prefill_chunk"
-        if is_first:
-            self._staging[slot] = self.model.init_cache(1, self.max_seq)
-        tokens = jnp.asarray(chunk, jnp.int32)[None]
-        logits, staging, expert_load = self._jit_chunk(
-            self.params, tokens, self._staging[slot],
-            jnp.asarray(start, jnp.int32), self._rt_arrays())
-        self._staging[slot] = staging
-        if is_last:
-            self.cache = jax.tree.map(
-                lambda big, one: _slot_write(big, one, slot),
-                self.cache, self._staging.pop(slot))
-        return logits, expert_load
+        with tracing.span("executor.prefill_chunk"):
+            if is_first:
+                self._staging[slot] = self.model.init_cache(1, self.max_seq)
+            tokens = jnp.asarray(chunk, jnp.int32)[None]
+            logits, staging, expert_load, self._dropped = self._jit_chunk(
+                self.params, tokens, self._staging[slot],
+                jnp.asarray(start, jnp.int32), self._rt_arrays())
+            self._staging[slot] = staging
+            if is_last:
+                self.cache = jax.tree.map(
+                    lambda big, one: _slot_write(big, one, slot),
+                    self.cache, self._staging.pop(slot))
+            return logits, expert_load
 
     # ------------------------------------------------------------- decode
     def decode(self, tokens: np.ndarray) -> Tuple[jax.Array, np.ndarray]:
         """One decode step over the whole slot batch -> (logits, load)."""
-        logits, self.cache, expert_load = self._jit_decode(
-            self.params, jnp.asarray(tokens), self.cache, self._rt_arrays())
-        return logits, expert_load
+        with tracing.span("executor.decode"):
+            logits, self.cache, expert_load, self._dropped = \
+                self._jit_decode(self.params, jnp.asarray(tokens),
+                                 self.cache, self._rt_arrays())
+            return logits, expert_load
 
     def decode_masked(self, tokens: np.ndarray, mask: np.ndarray
                       ) -> Tuple[jax.Array, np.ndarray]:
@@ -264,10 +291,12 @@ class Executor:
         engine reuse lockstep's values with different timing."""
         assert self._jit_masked is not None, \
             "decode_masked needs a uniform cache batch axis"
-        logits, self.cache, expert_load = self._jit_masked(
-            self.params, jnp.asarray(tokens), self.cache,
-            jnp.asarray(mask, bool), self._rt_arrays())
-        return logits, expert_load
+        with tracing.span("executor.decode"):
+            logits, self.cache, expert_load, self._dropped = \
+                self._jit_masked(self.params, jnp.asarray(tokens),
+                                 self.cache, jnp.asarray(mask, bool),
+                                 self._rt_arrays())
+            return logits, expert_load
 
     # -------------------------------------------------------------- paged
     def prefill_chunk_paged(self, chunk: np.ndarray, start: int,
@@ -279,23 +308,25 @@ class Executor:
         starts ``start`` past the cached prefix and the chunk attends over
         blocks an earlier request wrote.
         """
-        tokens = jnp.asarray(chunk, jnp.int32)[None]
-        logits, view, expert_load = self._jit_paged_chunk(
-            self.params, tokens, self.cache,
-            jnp.asarray(table_row, jnp.int32),
-            jnp.asarray(start, jnp.int32), self._rt_arrays())
-        self.cache = _adopt_pools(self.cache, view)
-        return logits, expert_load
+        with tracing.span("executor.prefill_chunk"):
+            tokens = jnp.asarray(chunk, jnp.int32)[None]
+            logits, view, expert_load, self._dropped = self._jit_paged_chunk(
+                self.params, tokens, self.cache,
+                jnp.asarray(table_row, jnp.int32),
+                jnp.asarray(start, jnp.int32), self._rt_arrays())
+            self.cache = _adopt_pools(self.cache, view)
+            return logits, expert_load
 
     def decode_paged(self, tokens: np.ndarray, tables: np.ndarray,
                      lengths: np.ndarray) -> Tuple[jax.Array, np.ndarray]:
         """One decode step with host-authoritative block tables/lengths."""
-        logits, cache, expert_load = self._jit_paged_decode(
-            self.params, jnp.asarray(tokens), self.cache,
-            jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
-            self._rt_arrays())
-        self.cache = cache
-        return logits, expert_load
+        with tracing.span("executor.decode"):
+            logits, self.cache, expert_load, self._dropped = \
+                self._jit_paged_decode(
+                    self.params, jnp.asarray(tokens), self.cache,
+                    jnp.asarray(tables, jnp.int32),
+                    jnp.asarray(lengths, jnp.int32), self._rt_arrays())
+            return logits, expert_load
 
     def copy_blocks(self, pairs) -> None:
         """Apply copy-on-write forks: pool blocks src -> dst, every layer."""

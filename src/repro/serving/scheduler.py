@@ -53,6 +53,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.serving.kv_pool import SCRATCH_BLOCK, BlockPool, block_hashes
 from repro.serving.request import Request
 
@@ -274,32 +275,33 @@ class Scheduler:
 
     def next_plan(self):
         """Admit what fits, then pick the next step per the active policy."""
-        self._admit()
-        pending = bool(self._progress)
-        ready = self.decode_ready()
-        policy = self.cfg.policy
-        if pending and ready:
-            if policy == "prefill-priority":
-                do_prefill = True
-            elif policy == "fcfs":
-                do_prefill = False
-            else:                        # fair: strict alternation
-                do_prefill = not self._last_was_prefill
-        else:
-            do_prefill = pending
-        if do_prefill:
-            plan = self._chunk_plan()
-            if plan is not None:
-                self._last_was_prefill = True
-                return plan
-            ready = self.decode_ready()  # planning may have preempted
-        self._last_was_prefill = False
-        if ready:
-            if self.kv is not None:
-                ready = self._ensure_decode_blocks(ready)
+        with tracing.span("sched.next_plan"):
+            self._admit()
+            pending = bool(self._progress)
+            ready = self.decode_ready()
+            policy = self.cfg.policy
+            if pending and ready:
+                if policy == "prefill-priority":
+                    do_prefill = True
+                elif policy == "fcfs":
+                    do_prefill = False
+                else:                        # fair: strict alternation
+                    do_prefill = not self._last_was_prefill
+            else:
+                do_prefill = pending
+            if do_prefill:
+                plan = self._chunk_plan()
+                if plan is not None:
+                    self._last_was_prefill = True
+                    return plan
+                ready = self.decode_ready()  # planning may have preempted
+            self._last_was_prefill = False
             if ready:
-                return DecodeBatch(slots=tuple(ready))
-        return Idle()
+                if self.kv is not None:
+                    ready = self._ensure_decode_blocks(ready)
+                if ready:
+                    return DecodeBatch(slots=tuple(ready))
+            return Idle()
 
     def prefill_advanced(self, slot: int, length: int) -> bool:
         """Record chunk completion; True when the slot became decode-ready."""

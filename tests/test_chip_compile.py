@@ -4,7 +4,8 @@ The TPU compiler is installed with jax and compiles for a described
 topology: each test lowers a Pallas kernel, or the served decode step, at
 the ``deepseek-r1-chip`` widths and compiles it, which raises what the
 chip's compiler would raise (unaligned blocks, refused kernel bodies,
-programs that do not fit).  Nothing runs, so nothing here says anything
+programs that do not fit); the compiled decode step must also keep the
+program's stage tags.  Nothing runs, so nothing here says anything
 about results or times.
 
 Only one process at a time may load the TPU library, so the topology is
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import tracing
 from repro.configs import get_config
 from repro.core.elastic import ServerPool
 from repro.kernels import ops
@@ -100,10 +102,11 @@ def test_combine_weighted_compiles(one_chip):
              _spec(one_chip, (T, k), jnp.float32))
 
 
-def test_decode_step_fits_one_chip(one_chip):
+@pytest.fixture(scope="module")
+def decode_step(one_chip):
     """The served decode step of the chip cut, as the executor jits it
     (params, tokens, cache and the runtime placement arrays are arguments),
-    with the expert GEMM ``auto`` picks on TPU."""
+    with the expert GEMM ``auto`` picks on TPU, compiled for the chip."""
     pool = ServerPool(CFG, 4, tokens_per_client=max(BATCH, CHUNK),
                       n_redundant=2)
     model = build_model(CFG, num_servers=4,
@@ -122,13 +125,26 @@ def test_decode_step_fits_one_chip(one_chip):
         logits, cache, st = model.decode_step(params, tokens, cache, ctx)
         return logits, cache, st.expert_load
 
-    compiled = _compile(
+    return _compile(
         decode,
         spec(jax.eval_shape(model.init_params, jax.random.PRNGKey(0))),
         _spec(one_chip, (BATCH, 1), jnp.int32),
         spec(model.init_cache(BATCH, SEQ, abstract=True)),
         spec((rt.mapping, rt.alive, rt.local_table, rt.route_bias)))
-    mem = compiled.memory_analysis()
+
+
+def test_decode_step_fits_one_chip(decode_step):
+    mem = decode_step.memory_analysis()
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert peak < HBM_BYTES, peak / 2 ** 30
+
+
+def test_decode_step_carries_stage_tags(decode_step):
+    """Every stage tag reaches the chip's program: the XLA ops' frontend
+    attribute, and the grouped GEMM kernel's own ``kernel_metadata``."""
+    text = decode_step.as_text()
+    for stage in ("attention", "router", "dispatch", "server", "combine",
+                  "head"):
+        assert f'{tracing.STAGE_KEY}="{stage}"' in text, stage
+    assert f'"{tracing.STAGE_KEY}":"gemm"' in text
