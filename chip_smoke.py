@@ -90,14 +90,16 @@ def require_tpu() -> dict:
 
 
 def compiles_line() -> str:
-    """The process's compiles so far, from :mod:`repro.tracing`'s
-    counters."""
+    """The process's compiles so far, dropped rows and grouped GEMM row
+    tiles, from :mod:`repro.tracing`'s counters."""
     c = tracing.counters()
     return (f"backend compiles {c.get('jax.compiles', 0):.0f}, "
             f"{c.get('jax.compile_s', 0.0):.1f} s; persistent-cache hits "
             f"{c.get('jax.cache_hits', 0):.0f}; inside engine steps "
             f"{c.get('engine.step_compiles', 0):.0f}; dropped rows "
-            f"{c.get('moe.dropped_rows', 0):.0f}")
+            f"{c.get('moe.dropped_rows', 0):.0f}; grouped GEMM row tiles "
+            f"live {c.get('gemm.tiles_live', 0):.0f} of "
+            f"{c.get('gemm.tiles', 0):.0f}")
 
 
 def _watch_logits(executor) -> list:

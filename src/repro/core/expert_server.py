@@ -206,6 +206,8 @@ def reshard_server_weights(server_w: Dict, num_experts: int,
 class ServeStats(NamedTuple):
     miss: jax.Array           # tokens whose expert this server doesn't host
     served: jax.Array         # valid tokens processed
+    tiles_live: jax.Array     # grouped GEMM row tiles run (Pallas only)
+    tiles: jax.Array          # grouped GEMM row tiles laid out (Pallas only)
 
 
 def serve(tokens: jax.Array, expert_ids: jax.Array, scores: jax.Array,
@@ -241,12 +243,12 @@ def serve(tokens: jax.Array, expert_ids: jax.Array, scores: jax.Array,
     # per-expert capacity for the dense lowering: ideal share × the buffer
     # capacity factor (under-provisioned experts drop, exactly like slots)
     ecap = max(8, ((-(-(M * 5) // (4 * L))) + 7) // 8 * 8)
-    gg = lambda a, w: kops.grouped_gemm(a, w, group_sizes, impl=impl,
-                                        expert_capacity=ecap)
-    h_gate = gg(xs, weights.w_gate)
-    h_up = gg(xs, weights.w_up)
+    gg = lambda a, w: kops.grouped_gemm_counted(a, w, group_sizes, impl=impl,
+                                                expert_capacity=ecap)
+    h_gate, t_gate = gg(xs, weights.w_gate)
+    h_up, t_up = gg(xs, weights.w_up)
     h = jax.nn.silu(h_gate.astype(jnp.float32)).astype(h_up.dtype) * h_up
-    y = gg(h, weights.w_down)
+    y, t_down = gg(h, weights.w_down)
 
     # ---- score weighting + masking, back to slot order ------------------
     y = y.astype(jnp.float32) * sc[order][:, None]
@@ -254,4 +256,7 @@ def serve(tokens: jax.Array, expert_ids: jax.Array, scores: jax.Array,
     y = jnp.where((valid[order] & hosted[order] & in_group)[:, None], y, 0)
     out = jnp.zeros((M, d), jnp.float32).at[order].set(y)
     out = out.reshape(Sc, C, d).astype(tokens.dtype)
-    return out, ServeStats(miss=miss, served=jnp.sum(valid & hosted))
+    return out, ServeStats(
+        miss=miss, served=jnp.sum(valid & hosted),
+        tiles_live=t_gate.live + t_up.live + t_down.live,
+        tiles=t_gate.total + t_up.total + t_down.total)

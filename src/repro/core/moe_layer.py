@@ -62,6 +62,8 @@ class MoEStats(NamedTuple):
     dropped: jax.Array         # tokens over slot capacity
     miss: jax.Array            # tokens sent to a server not hosting them
     expert_load: jax.Array     # (E,) token counts (feeds the load balancer)
+    gemm_tiles_live: jax.Array  # grouped GEMM row tiles run, all servers
+    gemm_tiles: jax.Array       # grouped GEMM row tiles laid out
 
 
 def default_capacity(tokens_per_client: int, top_k: int, num_servers: int,
@@ -194,13 +196,12 @@ def eaas_moe_apply(params: Dict, x: jax.Array, cfg_moe: MoEConfig,
             out_slots, st = jax.vmap(one_server)(
                 sw["w_gate"], sw["w_up"], sw["w_down"], runtime.local_table,
                 hid, eid, sc, cnt)
-            miss = jnp.sum(st.miss)
+            st = jax.tree.map(jnp.sum, st)
         else:
             w = ServerWeights(sw["w_gate"][0], sw["w_up"][0],
                               sw["w_down"][0], runtime.local_table[0])
             out_slots, st = expert_server.serve(hid, eid, sc, cnt, w,
                                                 impl=runtime.gemm_impl)
-            miss = st.miss
 
     # ---- client: combine (weighted sum arrives pre-weighted) -------------
     with tracing.stage("combine"):
@@ -213,7 +214,9 @@ def eaas_moe_apply(params: Dict, x: jax.Array, cfg_moe: MoEConfig,
         aux_loss=r.aux_loss,
         z_loss=r.z_loss,
         dropped=buffers.dropped,
-        miss=miss,
+        miss=st.miss,
         expert_load=expert_load,
+        gemm_tiles_live=st.tiles_live,
+        gemm_tiles=st.tiles,
     )
     return y, stats

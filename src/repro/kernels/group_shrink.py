@@ -11,9 +11,10 @@ into a *tile table* — for each of the (statically bounded) row tiles, the
 group it belongs to and whether it is live.  Empty groups simply never
 appear in the table; the only residual cost is the per-group tile-alignment
 padding (< TM rows per active group), and dead tail tiles are skipped with
-``pl.when`` at ~zero cost.  The tile table is consumed by the kernel through
-scalar prefetch (SMEM), i.e. loaded once — the analogue of the paper's
-"compacted tensor loaded into shared memory once".
+``pl.when`` and, holding the last live tile's group, fetch no weights.  The
+tile table is consumed by the kernel through scalar prefetch (SMEM), i.e.
+loaded once — the analogue of the paper's "compacted tensor loaded into
+shared memory once".
 """
 
 from __future__ import annotations
@@ -27,7 +28,8 @@ import jax.numpy as jnp
 class TileTable(NamedTuple):
     """Static-size, dynamically-valid tile metadata (scalar-prefetch input)."""
 
-    tile_gid: jax.Array       # (T,) int32 group id per row tile (0 if dead)
+    tile_gid: jax.Array       # (T,) int32 group per row tile; dead tiles
+    #                           repeat the last live tile's (0 if none)
     tile_valid: jax.Array     # (T,) int32 1 = live tile
     padded_offset: jax.Array  # (G,) int32 first padded row of each group
     num_tiles: jax.Array      # scalar int32 — live tile count (diagnostics)
@@ -56,8 +58,8 @@ def build_tile_table(group_sizes: jax.Array, m: int, tm: int) -> TileTable:
     tile_gid = tile_gid.at[jnp.where(has_tiles, first_tile, T)].max(
         jnp.arange(G, dtype=jnp.int32), mode="drop")
     tile_gid = jax.lax.associative_scan(jnp.maximum, tile_gid)
+    # the max-scan carries the last live group into the dead tail
     tile_valid = (jnp.arange(T) < num_tiles).astype(jnp.int32)
-    tile_gid = jnp.where(tile_valid > 0, tile_gid, 0)
     padded_offset = (first_tile * tm).astype(jnp.int32)
     return TileTable(tile_gid=tile_gid, tile_valid=tile_valid,
                      padded_offset=padded_offset,

@@ -27,7 +27,7 @@ sets ``xla_dense``; tests pin impls explicitly).
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,7 +37,7 @@ from repro.kernels import ref as kref
 from repro.kernels.combine import combine_weighted_pallas
 from repro.kernels.decode_attention import (flash_decode_pallas,
                                             paged_flash_decode_pallas)
-from repro.kernels.grouped_gemm import grouped_gemm_pallas
+from repro.kernels.grouped_gemm import RowTiles, grouped_gemm_pallas
 
 _DEFAULT_IMPL: Optional[str] = None
 
@@ -97,15 +97,17 @@ def grouped_gemm_dense(x_sorted: jax.Array, w: jax.Array,
 
 def grouped_gemm(x_sorted: jax.Array, w: jax.Array, group_sizes: jax.Array,
                  *, impl: str = "auto", expert_capacity: Optional[int] = None,
-                 tm: int = 128, tn: int = 128, tk: int = 128) -> jax.Array:
+                 tm: int = 128, tn: Optional[int] = None,
+                 tk: Optional[int] = None) -> jax.Array:
     """out[i] = x_sorted[i] @ w[g(i)] — see module docstring for impls.
     The XLA lowerings are traced in the ``gemm`` stage; the Pallas kernel
-    carries that tag in its own metadata."""
+    carries that tag in its own metadata, and takes the blocks not given
+    from the shapes (:func:`repro.kernels.grouped_gemm.choose_blocks`)."""
     impl = _resolve("grouped_gemm", impl, "xla_ragged", _GEMM_IMPLS)
     if impl in ("pallas", "pallas_interpret"):
         return grouped_gemm_pallas(
             x_sorted, w, group_sizes, tm=tm, tn=tn, tk=tk,
-            interpret=(impl == "pallas_interpret"))
+            interpret=(impl == "pallas_interpret"))[0]
     with tracing.stage("gemm"):
         if impl == "ref":
             return kref.grouped_gemm_ref(x_sorted, w, group_sizes)
@@ -118,6 +120,22 @@ def grouped_gemm(x_sorted: jax.Array, w: jax.Array, group_sizes: jax.Array,
         M, G = x_sorted.shape[0], w.shape[0]
         cap = expert_capacity or max(_ceil_mult(2 * M // max(G, 1) + 1, 8), 8)
         return grouped_gemm_dense(x_sorted, w, group_sizes, cap)
+
+
+def grouped_gemm_counted(x_sorted: jax.Array, w: jax.Array,
+                         group_sizes: jax.Array, *, impl: str = "auto",
+                         expert_capacity: Optional[int] = None
+                         ) -> Tuple[jax.Array, RowTiles]:
+    """:func:`grouped_gemm` and the Pallas kernel's row tiles, live and
+    laid out; the XLA lowerings have none and count zero."""
+    impl = _resolve("grouped_gemm", impl, "xla_ragged", _GEMM_IMPLS)
+    if impl in ("pallas", "pallas_interpret"):
+        return grouped_gemm_pallas(x_sorted, w, group_sizes,
+                                   interpret=(impl == "pallas_interpret"))
+    zero = jnp.zeros((), jnp.int32)
+    return (grouped_gemm(x_sorted, w, group_sizes, impl=impl,
+                         expert_capacity=expert_capacity),
+            RowTiles(zero, zero))
 
 
 def _ceil_mult(x: int, m: int) -> int:

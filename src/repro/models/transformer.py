@@ -137,6 +137,9 @@ def _moe_apply(params_moe: Dict, x2d: jax.Array, cfg: ModelConfig,
             miss=allsum(st.miss),
             expert_load=allsum(st.expert_load) // (
                 1 if mode == "a2a" else ctx.mesh.shape[model_ax]),
+            # every shard runs its own server's kernel
+            gemm_tiles_live=allsum(st.gemm_tiles_live),
+            gemm_tiles=allsum(st.gemm_tiles),
         )
         return y, st
 
@@ -145,7 +148,8 @@ def _moe_apply(params_moe: Dict, x2d: jax.Array, cfg: ModelConfig,
                            num_servers=None, capacity=None,
                            dispatch_method=None, gemm_impl=None)
     stats_specs = MoEStats(aux_loss=_P(), z_loss=_P(), dropped=_P(),
-                           miss=_P(), expert_load=_P())
+                           miss=_P(), expert_load=_P(),
+                           gemm_tiles_live=_P(), gemm_tiles=_P())
     fn = jax.shard_map(island, mesh=ctx.mesh,
                        in_specs=in_specs,
                        out_specs=(tok_spec, stats_specs),
@@ -378,10 +382,10 @@ def _scan_decode(blocks: Dict, caches, cfg: ModelConfig, x: jax.Array,
 
 def _zero_stats(cfg: ModelConfig) -> MoEStats:
     E = cfg.moe.num_experts if cfg.moe else 1
-    z = jnp.zeros(())
-    return MoEStats(aux_loss=z, z_loss=z, dropped=jnp.zeros((), jnp.int32),
-                    miss=jnp.zeros((), jnp.int32),
-                    expert_load=jnp.zeros((E,), jnp.int32))
+    z, zi = jnp.zeros(()), jnp.zeros((), jnp.int32)
+    return MoEStats(aux_loss=z, z_loss=z, dropped=zi, miss=zi,
+                    expert_load=jnp.zeros((E,), jnp.int32),
+                    gemm_tiles_live=zi, gemm_tiles=zi)
 
 
 def _sum_stats(*stats_list) -> MoEStats:
@@ -395,9 +399,8 @@ def _sum_stats(*stats_list) -> MoEStats:
         acc = red if acc is None else MoEStats(
             *[a + b for a, b in zip(acc, red)])
     if acc is None:
-        z = jnp.zeros(())
-        acc = MoEStats(z, z, jnp.zeros((), jnp.int32),
-                       jnp.zeros((), jnp.int32), jnp.zeros((1,), jnp.int32))
+        z, zi = jnp.zeros(()), jnp.zeros((), jnp.int32)
+        acc = MoEStats(z, z, zi, zi, jnp.zeros((1,), jnp.int32), zi, zi)
     return acc
 
 

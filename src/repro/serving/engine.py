@@ -323,6 +323,9 @@ class ServingEngine:
             kv_num_blocks=(self.kv_pool.num_blocks if self.kv_pool else 0))
         chunk = (engine_cfg.prefill_chunk
                  if self.executor.supports_chunked_prefill else 0)
+        # with chunking on, a one-chunk prompt runs the chunk program too:
+        # the whole-prompt one compiles for each prompt length
+        self._chunked = chunk > 0
         self.scheduler = Scheduler(SchedulerConfig(
             max_batch=engine_cfg.max_batch, prefill_chunk=chunk,
             policy=engine_cfg.policy,
@@ -758,7 +761,7 @@ class ServingEngine:
             self.executor.copy_blocks(plan.copies)     # pending COW forks
             logits, expert_load = self.executor.prefill_chunk_paged(
                 chunk, plan.start, self.scheduler.block_tables[b])
-        elif plan.is_first and plan.is_last:
+        elif plan.is_first and plan.is_last and not self._chunked:
             # whole prompt in one step — the pre-split prefill path
             logits = self.executor.prefill(b, chunk)
         else:
@@ -902,7 +905,7 @@ class ServingEngine:
                  else req.prompt[plan.start:plan.start + plan.length])
         self.clk.start()
         expert_load = None
-        if plan.is_first and plan.is_last:
+        if plan.is_first and plan.is_last and not self._chunked:
             logits = self.executor.prefill(b, chunk)
         else:
             logits, expert_load = self.executor.prefill_chunk(

@@ -33,9 +33,11 @@ new static server count — the AOT-per-server-count story.
 Each step method is a host span of its own name (``executor.decode``,
 ``executor.prefill_chunk``, ``executor.prefill``; :mod:`repro.tracing`);
 building the placement arrays is ``executor.runtime``.  The MoE step
-programs also return the rows their dispatch buffers dropped, summed over
-layers; :meth:`Executor.pull_load` brings that count to the host with the
-step's expert load, in one transfer, and adds it to ``moe.dropped_rows``.
+programs also return, summed over layers and servers, the rows their
+dispatch buffers dropped and the grouped GEMM's row tiles, live and laid
+out; :meth:`Executor.pull_load` brings them to the host with the step's
+expert load, in one transfer, and adds them to ``moe.dropped_rows``,
+``gemm.tiles_live`` and ``gemm.tiles``.
 """
 
 from __future__ import annotations
@@ -52,6 +54,15 @@ from repro.core import expert_server
 from repro.core.overlap import split_batch_decode
 from repro.models import kv_cache as kvc
 from repro.models.transformer import Model, ParallelCtx
+
+
+# the counters of :meth:`Executor.pull_load`, in the order of _counts
+COUNTERS = ("moe.dropped_rows", "gemm.tiles_live", "gemm.tiles")
+
+
+def _counts(st):
+    """What a MoE step adds to the :data:`COUNTERS`."""
+    return st.dropped, st.gemm_tiles_live, st.gemm_tiles
 
 
 class Executor:
@@ -103,7 +114,7 @@ class Executor:
         else:
             self.cache = model.init_cache(max_batch, max_seq)
         self._staging: Dict[int, object] = {}     # slot -> batch-1 cache
-        self._dropped = None          # rows dropped by the last MoE step
+        self._counts = None           # the last MoE step's _counts
         self._rt0 = pool.runtime(gemm_impl) if pool else None
         self._build_jits()
 
@@ -151,7 +162,7 @@ class Executor:
                     enabled=(self.decode_mode == "pipelined"))
             # per-expert token counts feed the pool's traffic EMA — this is
             # what rebalance() and traffic-aware scale_to re-plan from
-            return logits, cache, st.expert_load, st.dropped
+            return logits, cache, st.expert_load, _counts(st)
 
         self._jit_prefill = jax.jit(prefill_fn)
         self._jit_decode = jax.jit(decode_fn)
@@ -176,7 +187,7 @@ class Executor:
                     return jnp.where(mask.reshape(shape), new, old)
 
                 return (logits, jax.tree.map(keep, new_cache, cache),
-                        st.expert_load, st.dropped)
+                        st.expert_load, _counts(st))
             self._jit_masked = jax.jit(masked_decode_fn)
         self._jit_chunk = None
         if model.prefill_chunk is not None:
@@ -185,7 +196,7 @@ class Executor:
                     params, tokens, cache, start, ctx_of(rt_arrays))
                 # chunked prefill feeds the traffic EMA like decode does —
                 # the prompt-heavy-workload rebalance signal
-                return logits, cache, st.expert_load, st.dropped
+                return logits, cache, st.expert_load, _counts(st)
             self._jit_chunk = jax.jit(chunk_fn)
 
         if self.kv_mode == "paged":
@@ -196,14 +207,14 @@ class Executor:
                 cache = _with_tables(cache, tables, lengths)
                 logits, cache, st = decode_step(params, tokens, cache,
                                                 rt_arrays)
-                return logits, cache, st.expert_load, st.dropped
+                return logits, cache, st.expert_load, _counts(st)
 
             def paged_chunk_fn(params, tokens, cache, row, start, rt_arrays):
                 view = _with_tables(cache, row[None],
                                     jnp.broadcast_to(start, (1,)))
                 logits, view, st = model.prefill_chunk(
                     params, tokens, view, start, ctx_of(rt_arrays))
-                return logits, view, st.expert_load, st.dropped
+                return logits, view, st.expert_load, _counts(st)
 
             def copy_fn(cache, src, dst):
                 return {k: kvc.copy_blocks(st, src, dst, stacked=True)
@@ -223,15 +234,16 @@ class Executor:
 
     def pull_load(self, expert_load) -> Optional[np.ndarray]:
         """The step's expert load on the host, fetched in one transfer with
-        the rows its dispatch dropped, which add to ``moe.dropped_rows``;
-        None without an expert tier or a load.  This waits for the step
+        its :func:`_counts`, which add to the :data:`COUNTERS`; None
+        without an expert tier or a load.  This waits for the step
         (``executor.wait``)."""
         if expert_load is None or self.pool is None:
             return None
         with tracing.span("executor.wait"):
-            load, dropped = jax.device_get((expert_load, self._dropped))
-        if dropped is not None:
-            tracing.count("moe.dropped_rows", int(dropped))
+            load, counts = jax.device_get((expert_load, self._counts))
+        if counts is not None:
+            for name, n in zip(COUNTERS, counts):
+                tracing.count(name, int(n))
         return np.asarray(load)
 
     # ------------------------------------------------------------ prefill
@@ -261,7 +273,7 @@ class Executor:
             if is_first:
                 self._staging[slot] = self.model.init_cache(1, self.max_seq)
             tokens = jnp.asarray(chunk, jnp.int32)[None]
-            logits, staging, expert_load, self._dropped = self._jit_chunk(
+            logits, staging, expert_load, self._counts = self._jit_chunk(
                 self.params, tokens, self._staging[slot],
                 jnp.asarray(start, jnp.int32), self._rt_arrays())
             self._staging[slot] = staging
@@ -275,7 +287,7 @@ class Executor:
     def decode(self, tokens: np.ndarray) -> Tuple[jax.Array, np.ndarray]:
         """One decode step over the whole slot batch -> (logits, load)."""
         with tracing.span("executor.decode"):
-            logits, self.cache, expert_load, self._dropped = \
+            logits, self.cache, expert_load, self._counts = \
                 self._jit_decode(self.params, jnp.asarray(tokens),
                                  self.cache, self._rt_arrays())
             return logits, expert_load
@@ -292,7 +304,7 @@ class Executor:
         assert self._jit_masked is not None, \
             "decode_masked needs a uniform cache batch axis"
         with tracing.span("executor.decode"):
-            logits, self.cache, expert_load, self._dropped = \
+            logits, self.cache, expert_load, self._counts = \
                 self._jit_masked(self.params, jnp.asarray(tokens),
                                  self.cache, jnp.asarray(mask, bool),
                                  self._rt_arrays())
@@ -310,7 +322,7 @@ class Executor:
         """
         with tracing.span("executor.prefill_chunk"):
             tokens = jnp.asarray(chunk, jnp.int32)[None]
-            logits, view, expert_load, self._dropped = self._jit_paged_chunk(
+            logits, view, expert_load, self._counts = self._jit_paged_chunk(
                 self.params, tokens, self.cache,
                 jnp.asarray(table_row, jnp.int32),
                 jnp.asarray(start, jnp.int32), self._rt_arrays())
@@ -321,7 +333,7 @@ class Executor:
                      lengths: np.ndarray) -> Tuple[jax.Array, np.ndarray]:
         """One decode step with host-authoritative block tables/lengths."""
         with tracing.span("executor.decode"):
-            logits, self.cache, expert_load, self._dropped = \
+            logits, self.cache, expert_load, self._counts = \
                 self._jit_paged_decode(
                     self.params, jnp.asarray(tokens), self.cache,
                     jnp.asarray(tables, jnp.int32),

@@ -2,8 +2,9 @@
 
 The TPU compiler is installed with jax and compiles for a described
 topology: each test lowers a Pallas kernel, or the served decode step, at
-the ``deepseek-r1-chip`` widths and compiles it, which raises what the
-chip's compiler would raise (unaligned blocks, refused kernel bodies,
+the ``deepseek-r1-chip`` widths (the grouped GEMM at Mixtral-8x7B's too)
+and compiles it, which raises what the chip's compiler would raise
+(unaligned blocks, refused kernel bodies, blocks that overflow VMEM,
 programs that do not fit); the compiled decode step must also keep the
 program's stage tags.  Nothing runs, so nothing here says anything
 about results or times.
@@ -29,6 +30,7 @@ from repro.models.transformer import ParallelCtx, build_model
 HBM_BYTES = 16 * 2 ** 30          # one v5e chip
 CFG = get_config("deepseek-r1-chip")
 BATCH, SEQ, CHUNK = 32, 2048, 64  # the smoke's engine shape
+MIXTRAL_D, MIXTRAL_F = 4096, 14336   # bench/configs/mixtral-8x7b.pp8.json
 
 
 @pytest.fixture(scope="module")
@@ -61,15 +63,25 @@ def _compile(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("k,n", [(CFG.d_model, CFG.moe.d_expert),
-                                 (CFG.moe.d_expert, CFG.d_model)],
-                         ids=["gate_up", "down"])
-def test_grouped_gemm_compiles(one_chip, k, n):
-    groups = 6                    # 4 primaries + 2 redundant slots
-    _compile(lambda x, w, g: ops.grouped_gemm(x, w, g, impl="pallas"),
-             _spec(one_chip, (256, k), jnp.bfloat16),
-             _spec(one_chip, (groups, k, n), jnp.bfloat16),
-             _spec(one_chip, (groups,), jnp.int32))
+@pytest.mark.parametrize("servers,rows,groups,k,n", [
+    # the chip cut: 4 primaries + 2 redundant slots on one server
+    (None, 256, 6, CFG.d_model, CFG.moe.d_expert),
+    (None, 256, 6, CFG.moe.d_expert, CFG.d_model),
+    # Mixtral-8x7B's stage: 4 servers vmapped, 2 experts and 264 rows each
+    (4, 264, 2, MIXTRAL_D, MIXTRAL_F),
+    (4, 264, 2, MIXTRAL_F, MIXTRAL_D),
+], ids=["gate_up", "down", "mixtral_gate_up", "mixtral_down"])
+def test_grouped_gemm_compiles(one_chip, servers, rows, groups, k, n):
+    """The blocks chosen from these shapes compile and fit the chip's
+    VMEM."""
+    gemm = lambda x, w, g: ops.grouped_gemm(x, w, g, impl="pallas")
+    lead = ()
+    if servers:
+        gemm, lead = jax.vmap(gemm), (servers,)
+    _compile(gemm,
+             _spec(one_chip, (*lead, rows, k), jnp.bfloat16),
+             _spec(one_chip, (*lead, groups, k, n), jnp.bfloat16),
+             _spec(one_chip, (*lead, groups), jnp.int32))
 
 
 def test_flash_decode_compiles(one_chip):

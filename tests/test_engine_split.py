@@ -122,6 +122,27 @@ def test_chunked_prefill_bounds_max_itl(cfg):
     assert m_ch.itl_stats()["max"] < m_un.itl_stats()["max"]
 
 
+def test_one_chunk_prompt_runs_the_chunk_program(cfg):
+    """With chunking on, a prompt that fits one chunk runs the chunk
+    program, which every chunk length shares, not the whole-prompt one,
+    which compiles for each prompt length."""
+    eng = _engine(cfg, prefill_chunk=16)
+    calls = []
+    for name in ("prefill", "prefill_chunk"):
+        fn = getattr(eng.executor, name)
+
+        def spy(*a, _name=name, _fn=fn, **kw):
+            calls.append(_name)
+            return _fn(*a, **kw)
+        setattr(eng.executor, name, spy)
+    for rid, n in enumerate((8, 16, 40)):
+        eng.submit(Request(rid, np.arange(1, n + 1, dtype=np.int32),
+                           SamplingParams(max_new_tokens=2)))
+    eng.run()
+    assert calls.count("prefill_chunk") == 1 + 1 + 3
+    assert "prefill" not in calls
+
+
 def test_ttft_tracked(cfg):
     eng, res = _run(cfg)
     m = res.metrics

@@ -6,22 +6,32 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels import group_shrink as gs
+from repro.kernels import grouped_gemm as gg
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("m,k,n,g,tm", [
-    (64, 32, 32, 4, 8),
-    (128, 64, 48, 6, 16),
-    (96, 128, 128, 3, 32),
-    (256, 64, 128, 16, 8),
-])
-def test_grouped_gemm_pallas_vs_ref(m, k, n, g, tm, dtype, rng):
-    sizes = rng.multinomial(m - 8, np.ones(g) / g).astype(np.int32)  # pad 8
+@pytest.mark.parametrize("m,k,n,g,tm,blocks", [
+    (64, 32, 32, 4, 8, 16),
+    (128, 64, 48, 6, 16, 16),
+    (96, 128, 128, 3, 32, 16),
+    (256, 64, 128, 16, 8, 16),
+    # blocks chosen from the shapes (above 128), with empty groups and a
+    # row count no multiple of tm, so dead tiles follow the live ones
+    (200, 512, 768, 5, 32, None),
+    (72, 768, 256, 7, 16, None),
+], ids=["64-32-32-4-8", "128-64-48-6-16", "96-128-128-3-32", "256-64-128-16-8",
+        "200-512-768-5-32-chosen", "72-768-256-7-16-chosen"])
+def test_grouped_gemm_pallas_vs_ref(m, k, n, g, tm, blocks, dtype, rng):
+    p = np.ones(g) / g
+    if blocks is None:
+        p[1::2] = 0                                      # empty groups
+        p /= p.sum()
+    sizes = rng.multinomial(m - 8, p).astype(np.int32)  # pad 8
     x = jnp.asarray(rng.normal(size=(m, k)), dtype)
     w = jnp.asarray(rng.normal(size=(g, k, n)) * 0.1, dtype)
     gsz = jnp.asarray(sizes)
     out = ops.grouped_gemm(x, w, gsz, impl="pallas_interpret",
-                           tm=tm, tn=16, tk=16)
+                           tm=tm, tn=blocks, tk=blocks)
     exp = ref.grouped_gemm_ref(x, w, gsz)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -50,6 +60,40 @@ def test_grouped_gemm_empty_groups(rng):
     out = ops.grouped_gemm(x, w, gsz, impl="pallas_interpret",
                            tm=8, tn=8, tk=8)
     assert np.allclose(out, 0)
+
+
+@pytest.mark.parametrize("k,n", [(4096, 14336), (14336, 4096),
+                                 (7168, 2048), (2048, 7168), (128, 128)])
+def test_blocks_chosen_from_shapes(k, n):
+    tk, tn = gg.choose_blocks(k, n)
+    assert k % tk == 0 and n % tn == 0
+    assert tk % 128 == 0 and tn % 128 == 0
+    assert gg.working_set(128, tk, tn) <= gg.VMEM_BUDGET
+    if k * n >= 2048 * 1024:           # published widths: MiB-sized blocks
+        assert tk * tn * 2 >= 2 * 2 ** 20, (tk, tn)
+
+
+def test_dead_tiles_fetch_the_last_live_block():
+    """Dead tail tiles carry the last live tile's group, so their weight
+    block index is the one the grid step before them holds."""
+    table = gs.build_tile_table(jnp.array([9, 0, 20, 0], jnp.int32),
+                                m=40, tm=8)
+    assert int(table.num_tiles) == 2 + 3
+    assert list(np.asarray(table.tile_gid)) == [0, 0, 2, 2, 2, 2, 2, 2, 2]
+    empty = gs.build_tile_table(jnp.zeros((3,), jnp.int32), m=16, tm=8)
+    assert int(empty.num_tiles) == 0
+    assert not np.asarray(empty.tile_gid).any()
+
+
+def test_row_tiles_are_counted(rng):
+    sizes = jnp.array([10, 0, 40, 30, 16], jnp.int32)
+    x = jnp.asarray(rng.normal(size=(128, 16)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(5, 16, 16)), jnp.float32)
+    _, tiles = ops.grouped_gemm_counted(x, w, sizes, impl="pallas_interpret")
+    # tm = 128: one tile for each non-empty group, of 128 // 128 + 5
+    assert (int(tiles.live), int(tiles.total)) == (4, 6)
+    _, none = ops.grouped_gemm_counted(x, w, sizes, impl="xla_ragged")
+    assert (int(none.live), int(none.total)) == (0, 0)
 
 
 def test_tile_table_shrinks_inactive_groups():

@@ -1,7 +1,7 @@
 """repro.tracing on the CPU: the span ring, the counters, the compile
-listener, the MoE layer's dropped-row count, and the stage tags of the
-compiled decode program (every stage is there, and the tags change
-nothing the program computes)."""
+listener, the MoE layer's dropped-row and grouped GEMM tile counts, and the
+stage tags of the compiled decode program (every stage is there, and the
+tags change nothing the program computes)."""
 
 import contextlib
 import importlib.util
@@ -25,7 +25,7 @@ STAGES = ("attention", "router", "dispatch", "server", "gemm", "combine",
 INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%\S+ = ", re.M)
 
 
-def _tiny_config(pool_tokens=None) -> dict:
+def _tiny_config(pool_tokens=None, gemm_impl=None) -> dict:
     """The benchmark's configuration at the tiny widths of
     ``tests/bench/bench_tiny.py``, whose buffers are sized dropless."""
     spec = importlib.util.spec_from_file_location(
@@ -35,11 +35,13 @@ def _tiny_config(pool_tokens=None) -> dict:
     cfg = mod.tiny_config()
     if pool_tokens is not None:
         cfg["serving"]["pool_tokens_per_client"] = pool_tokens
+    if gemm_impl is not None:
+        cfg["serving"]["gemm_impl"] = gemm_impl
     return cfg
 
 
-def _engine(pool_tokens=None) -> ServingEngine:
-    cfg = _tiny_config(pool_tokens)
+def _engine(pool_tokens=None, gemm_impl=None) -> ServingEngine:
+    cfg = _tiny_config(pool_tokens, gemm_impl)
     a = arch_of(cfg)
     return ServingEngine(system.model_config(cfg, a),
                          system.engine_config(cfg["serving"]), seed=3)
@@ -137,6 +139,20 @@ def test_dropped_rows_are_counted(pool_tokens, dropless):
     _serve(eng)
     dropped = tracing.counters().get("moe.dropped_rows", 0) - before
     assert (dropped == 0) if dropless else (dropped > 0)
+
+
+@pytest.mark.parametrize("gemm_impl, counted",
+                         [("pallas_interpret", True), ("xla_ragged", False)])
+def test_gemm_row_tiles_are_counted(gemm_impl, counted):
+    """The Pallas kernel's row tiles, live and laid out, reach the
+    counters; the XLA lowering has none."""
+    names = ("gemm.tiles_live", "gemm.tiles")
+    eng = _engine(gemm_impl=gemm_impl)
+    before = [tracing.counters().get(k, 0) for k in names]
+    _serve(eng, n=2, prompt=32, new=3)
+    live, total = (tracing.counters().get(k, 0) - b
+                   for k, b in zip(names, before))
+    assert (0 < live <= total) if counted else (live == total == 0)
 
 
 def test_every_stage_is_in_the_decode_program():
