@@ -30,8 +30,8 @@ def main(name: str) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import reference, system, weights
-    from bench.arch import arch_of, load_config
+    from bench import weights
+    from bench.models import family, load_config
     from repro.core.elastic import ServerPool
     from repro.models.transformer import ParallelCtx, build_model
 
@@ -40,9 +40,10 @@ def main(name: str) -> int:
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
     cfg = load_config(name)
-    a = arch_of(cfg)
+    fam = family(cfg)
+    a = fam.arch(cfg)
     s = cfg["serving"]
-    mcfg = system.model_config(cfg, a)
+    mcfg = fam.program_config(cfg, a)
     pool = ServerPool(mcfg, s["num_servers"],
                       tokens_per_client=s["pool_tokens_per_client"],
                       n_redundant=s["n_redundant"])
@@ -76,7 +77,7 @@ def main(name: str) -> int:
           f"expert slots {slots.tolist()}", flush=True)
 
     report("weight draw", jax.jit(
-        weights.served_init(a, params, slots), out_shardings=chip).lower(
+        fam.served_init(a, params, slots), out_shardings=chip).lower(
             spec(jax.ShapeDtypeStruct((2,), jnp.uint32))).compile())
     report("decode", jax.jit(lambda p, t, c, r: model.decode_step(
         p, t, c, ctx_of(r))[:2]).lower(
@@ -87,10 +88,11 @@ def main(name: str) -> int:
         params, spec(jax.ShapeDtypeStruct((1, C), jnp.int32)),
         spec(model.init_cache(1, S, abstract=True)),
         spec(jax.ShapeDtypeStruct((), jnp.int32)), rts).compile())
-    w = jax.eval_shape(lambda k: reference._draw_layer(a, k, 1, True, None),
+    draw, apply = fam.reference_layer(a)
+    w = jax.eval_shape(lambda k: draw(k, a.first_k_dense, True, None),
                        jax.random.PRNGKey(0))
     report("reference MoE layer (16 x 2048 tokens, float32)",
-           jax.jit(lambda w, x: reference._layer(a, w, x)).lower(
+           jax.jit(lambda w, x: apply(w, x)).lower(
                spec(w), spec(jax.ShapeDtypeStruct((16, S, a.d_model),
                                                   jnp.float32))).compile())
     return 0

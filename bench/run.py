@@ -4,7 +4,8 @@
     python3 bench/run.py --workload mx.reason --seed 7 --seconds 10 --trace 0
 
 A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a configuration
-(``bench/configs/<name>.json``) under a traffic mix
+(``bench/configs/<name>.json``, whose ``model_type`` names its family module
+``bench/models/<model_type>.py``) under a traffic mix
 (``bench/traffic/<name>.json``).  One run builds the configuration's
 serving system with weights drawn on the device from ``--seed``, serves the
 mix's set-up traffic (that warms every program the window uses), offers
@@ -56,7 +57,7 @@ def load_benchmark() -> dict:
 
 def cell_parts(bm: dict, name: str):
     """(cell, configuration file, traffic mix) of a named cell."""
-    from bench.arch import load_config
+    from bench.models import load_config
 
     cells = {c["name"]: c for c in bm["workloads"]}
     if name not in cells:
@@ -112,11 +113,13 @@ def enable_compile_cache() -> str:
 
 
 class Context:
-    """What a per-layer reader sees."""
+    """What a per-layer reader sees; ``family`` is the configuration's
+    family module, which counts its operations."""
 
-    def __init__(self, rec, arch, peaks, reduced):
+    def __init__(self, rec, arch, peaks, reduced, family):
         self.rec = rec
         self.arch = arch
+        self.family = family
         self.peaks = peaks
         self.trace = reduced
         self.window_s = rec.t1 - rec.t0
@@ -168,12 +171,13 @@ def run_cell(cell: dict, cfg: dict, mix: dict, *, seed: int, seconds: float,
 
     from bench import system, workload
     from bench import trace as trace_mod
-    from bench.arch import arch_of
     from bench.compiles import CompileCounter
     from bench.drive import Driver
+    from bench.models import family
 
     counter = CompileCounter()
-    a = arch_of(cfg)
+    fam = family(cfg)
+    a = fam.arch(cfg)
     s = cfg["serving"]
     planned = workload.plan(mix, seed=seed, seconds=seconds, vocab=a.vocab,
                             max_seq=s["max_seq"], chunk=s["prefill_chunk"],
@@ -248,7 +252,7 @@ def run_cell(cell: dict, cfg: dict, mix: dict, *, seed: int, seconds: float,
         dev["window_s"] = reduced["window_s"]
         breakdown = {"device_ops": reduced["device_ops"],
                      "idle_gaps": reduced["idle_gaps"]}
-        ctx = Context(rec, a, peaks, reduced)
+        ctx = Context(rec, a, peaks, reduced, fam)
         for m in metric_names:
             v = read_metric(m["name"], ctx)
             if v is not None:
@@ -262,7 +266,7 @@ def run_cell(cell: dict, cfg: dict, mix: dict, *, seed: int, seconds: float,
 
     t_ref = time.perf_counter()
     picked = [served[r] + (program_logits[r],) for r in sample]
-    judged = check(a, cfg, seed, picked, controls=controls,
+    judged = check(fam, a, cfg, seed, picked, controls=controls,
                    batch=int(mix["check"]["requests"]))
     log(f"reference over {len(sample)} requests "
         f"{time.perf_counter() - t_ref:.1f} s")
@@ -312,15 +316,15 @@ def judge(ref, got, tokens, limits: dict):
     return checked, readings, correct
 
 
-def check(a, cfg: dict, seed: int, sample, controls=(), batch: int = 0):
+def check(fam, a, cfg: dict, seed: int, sample, controls=(),
+          batch: int = 0):
     """Judge what the served path produced for ``sample`` ((prompt, served
     tokens, the logits each was chosen from) triples) against the float32
-    reference.  Returns ``{"served": (checked, readings, correct)}``, and
-    the same for each control in ``controls`` (``int8``, ``fp8``): the
-    reference computed in that precision, put in the served path's place
-    at the same positions, with the tokens it puts first."""
-    from bench import reference
-
+    reference of the configuration's family module ``fam``.  Returns
+    ``{"served": (checked, readings, correct)}``, and the same for each
+    control in ``controls`` (``int8``, ``fp8``): the reference computed in
+    that precision, put in the served path's place at the same positions,
+    with the tokens it puts first."""
     complete = bool(sample) and all(
         len(o) and all(x is not None for x in lg) for _, o, lg in sample)
     if not complete:
@@ -335,11 +339,11 @@ def check(a, cfg: dict, seed: int, sample, controls=(), batch: int = 0):
     # one padded shape for every run of the configuration: its programs
     # compile once and come from the cache after
     pad = dict(pad_to=cfg["serving"]["max_seq"], batch=batch)
-    ref = reference.forward(a, seed, seqs, reads, **pad)
+    ref = fam.forward(a, seed, seqs, reads, **pad)
     out = {"served": judge(ref, got, [o for _, o, _ in sample],
                            cfg["check"])}
     for q in controls:
-        low = reference.forward(a, seed, seqs, reads, quant=q, **pad)
+        low = fam.forward(a, seed, seqs, reads, quant=q, **pad)
         out[q] = judge(ref, low, [x.argmax(-1) for x in low], cfg["check"])
     return out
 

@@ -36,6 +36,18 @@ def by_stage(reduced: Dict) -> Dict[Optional[str], float]:
     return dict(out)
 
 
+def kernel_seconds(reduced: Dict, stage: str) -> Optional[float]:
+    """Own device seconds inside the window of the kernels that carry
+    ``stage`` in their own ``kernel_metadata``, or None where none ran.
+    An XLA operation that only lies in the stage's region (a row pad or
+    unpad around the kernel) is not one of them."""
+    text = reduced["op_text"]
+    hits = [seconds for name, seconds in reduced["op_seconds"].items()
+            if (m := _KERNEL_TAG.search(text.get(name, name)))
+            and m.group(1) == stage]
+    return sum(hits) if hits else None
+
+
 def ms_per_step(ctx, stages) -> Optional[float]:
     """Own device time of the operations tagged with any of ``stages``, in
     ms per engine step of the window (the steps that ran a prefill chunk

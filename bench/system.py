@@ -1,11 +1,12 @@
 """The system under test, built as a configuration file states it.
 
 This is the benchmark's one seam into the program: it turns the file's
-published keys and ``serving`` block into the program's ``ModelConfig`` and
-``EngineConfig``, builds a ``repro.serving.Cluster`` around a recording
-wall clock, and loads the benchmark's own weights into it.  ``Cluster``
-takes no parameters, so the engine's weight initialiser is swapped for the
-benchmark's loader while the cluster is built.
+published keys (through its family module, ``bench/models``) and its
+``serving`` block into the program's ``ModelConfig`` and ``EngineConfig``,
+builds a ``repro.serving.Cluster`` around a recording wall clock, and loads
+the benchmark's own weights into it.  ``Cluster`` takes no parameters, so
+the engine's weight initialiser is swapped for the benchmark's loader
+while the cluster is built.
 """
 
 from __future__ import annotations
@@ -17,26 +18,13 @@ from typing import Dict, List, Tuple
 import jax
 
 from bench import weights
-from bench.arch import Arch
+from bench.models import family
 
 
-def model_config(cfg: Dict, a: Arch):
-    from repro.configs.base import ModelConfig, MoEConfig
-
-    return ModelConfig(
-        arch_id=a.name, family="moe", num_layers=a.num_layers,
-        d_model=a.d_model, num_heads=a.num_heads,
-        num_kv_heads=a.num_kv_heads, d_ff=a.d_ff, vocab_size=a.vocab,
-        d_head=a.head_dim, rope_theta=a.rope_theta,
-        rms_norm_eps=a.rms_norm_eps, activation="swiglu",
-        moe=MoEConfig(num_experts=a.num_experts, top_k=a.top_k,
-                      d_expert=a.d_expert,
-                      num_shared_experts=a.num_shared_experts,
-                      first_k_dense=a.first_k_dense,
-                      router_score_fn=a.score_fn,
-                      normalize_topk=a.normalize_topk),
-        max_seq_len=cfg["serving"]["max_seq"], dtype="bfloat16",
-        source=cfg["source"])
+def model_config(cfg: Dict, a):
+    """The program's ``ModelConfig`` of a configuration file, by its
+    family."""
+    return family(cfg).program_config(cfg, a)
 
 
 def engine_config(serving: Dict):
@@ -67,7 +55,7 @@ def recording_clock():
 
 
 @contextmanager
-def _loader(a: Arch, seed: int, timings: Dict):
+def _loader(served_params, a, seed: int, timings: Dict):
     """Swap the engine's weight initialiser for the benchmark's draw."""
     from repro.models.transformer import build_model
     from repro.serving import engine
@@ -81,7 +69,7 @@ def _loader(a: Arch, seed: int, timings: Dict):
         slots = weights.slot_experts(cfg.moe.num_experts, num_servers,
                                      redundant_table)
         t0 = time.perf_counter()
-        params = weights.served_params(a, seed, want, slots)
+        params = served_params(a, seed, want, slots)
         jax.block_until_ready(params)
         timings["weights_s"] = time.perf_counter() - t0
         return params
@@ -93,7 +81,7 @@ def _loader(a: Arch, seed: int, timings: Dict):
         engine.init_params = program_init
 
 
-def build(cfg: Dict, a: Arch, seed: int):
+def build(cfg: Dict, a, seed: int):
     """(cluster, the client engine, its recording clock, timings)."""
     from repro.serving import Cluster, ClusterConfig
 
@@ -101,8 +89,9 @@ def build(cfg: Dict, a: Arch, seed: int):
     if s["clients"] != 1:
         raise ValueError("the harness drives one attention client")
     timings: Dict = {}
-    with _loader(a, seed, timings):
-        cluster = Cluster(model_config(cfg, a), ClusterConfig(
+    fam = family(cfg)
+    with _loader(fam.served_params, a, seed, timings):
+        cluster = Cluster(fam.program_config(cfg, a), ClusterConfig(
             clients=1, engine=engine_config(s), max_clients=1),
             seed=seed, clock_factory=recording_clock)
     eng = cluster.clients[0]

@@ -1,11 +1,17 @@
 """The model's weights, made by the benchmark from the seed.
 
 Every weight is a function of ``(seed, layer, name)`` — and, for a routed
-expert, of its expert id — so the served copy and the reference draw the
-same numbers without either handing them to the other.  The served copy
-is built on the device in one jitted call, in the types the program
-serves them in, and laid out as the program's own parameter tree; the
-reference draws one layer at a time in float32.
+expert, of its global expert id — so the served copy and the reference
+draw the same numbers without either handing them to the other.  The
+served copy is built on the device in one jitted call, in the types the
+program serves them in, and laid out as the program's own parameter tree;
+the reference draws one layer at a time in float32.
+
+This module holds what every family draws alike (``bench/models``): the
+primitives, the embedding, head and final norm, the SwiGLU FFN, the
+routed experts and their server slots, and the check of a drawn tree
+against the program's.  A family draws its other weights (attention, the
+router) under tags of its own, outside :data:`TAG`'s.
 
 Scales keep every layer's output near unit size: matrices are normal with
 standard deviation ``1/sqrt(fan_in)``, embeddings are standard normal and
@@ -20,15 +26,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.arch import Arch
-
 BF16 = jnp.bfloat16
 F32 = jnp.float32
 
-# tags folded into a layer's key, one per weight
-_TAG = {"ln1": 0, "ln2": 1, "wq": 2, "wk": 3, "wv": 4, "wo": 5,
-        "ffn_gate": 6, "ffn_up": 7, "ffn_down": 8, "router": 9,
-        "experts": 10}
+# tags folded into a layer's key, one per weight drawn here
+TAG = {"ffn_gate": 6, "ffn_up": 7, "ffn_down": 8, "experts": 10}
 
 
 def base_key(seed: int) -> jax.Array:
@@ -51,7 +53,7 @@ def layer_key(key: jax.Array, layer) -> jax.Array:
     return jax.random.fold_in(jax.random.fold_in(key, 1), layer)
 
 
-def embed(key: jax.Array, a: Arch, rows: int) -> jax.Array:
+def embed(key: jax.Array, a, rows: int) -> jax.Array:
     """(rows, d) token embeddings, row ``r`` drawn from its own key, so the
     rows do not depend on how far the table is padded."""
     k = jax.random.fold_in(key, 2)
@@ -60,7 +62,7 @@ def embed(key: jax.Array, a: Arch, rows: int) -> jax.Array:
             jnp.arange(rows)).astype(BF16)
 
 
-def head(key: jax.Array, a: Arch, cols: int) -> jax.Array:
+def head(key: jax.Array, a, cols: int) -> jax.Array:
     """(d, cols) output head, column ``c`` drawn from its own key."""
     k = jax.random.fold_in(key, 3)
     return (jax.vmap(lambda c: jax.random.normal(
@@ -68,39 +70,23 @@ def head(key: jax.Array, a: Arch, cols: int) -> jax.Array:
         * (1.0 / np.sqrt(a.d_model))).astype(BF16)
 
 
-def final_norm(key: jax.Array, a: Arch) -> jax.Array:
+def final_norm(key: jax.Array, a) -> jax.Array:
     return _norm(jax.random.fold_in(key, 4), a.d_model)
 
 
-def attention(lk: jax.Array, a: Arch) -> Dict[str, jax.Array]:
-    k = lambda name: jax.random.fold_in(lk, _TAG[name])
-    q_w, kv_w = a.num_heads * a.head_dim, a.num_kv_heads * a.head_dim
-    return {"ln1": _norm(k("ln1"), a.d_model),
-            "ln2": _norm(k("ln2"), a.d_model),
-            "wq": _matrix(k("wq"), a.d_model, q_w),
-            "wk": _matrix(k("wk"), a.d_model, kv_w),
-            "wv": _matrix(k("wv"), a.d_model, kv_w),
-            "wo": _matrix(k("wo"), q_w, a.d_model)}
-
-
-def ffn(lk: jax.Array, a: Arch, width: int) -> Dict[str, jax.Array]:
+def ffn(lk: jax.Array, a, width: int) -> Dict[str, jax.Array]:
     """The layer's dense SwiGLU FFN: the dense layers' FFN, or the shared
     experts of an MoE layer (one FFN of their summed width)."""
-    k = lambda name: jax.random.fold_in(lk, _TAG[name])
+    k = lambda name: jax.random.fold_in(lk, TAG[name])
     return {"w_gate": _matrix(k("ffn_gate"), a.d_model, width),
             "w_up": _matrix(k("ffn_up"), a.d_model, width),
             "w_down": _matrix(k("ffn_down"), width, a.d_model)}
 
 
-def router(lk: jax.Array, a: Arch) -> jax.Array:
-    return _matrix(jax.random.fold_in(lk, _TAG["router"]), a.d_model,
-                   a.num_experts, F32)
-
-
-def expert(lk: jax.Array, a: Arch, e) -> Dict[str, jax.Array]:
+def expert(lk: jax.Array, a, e) -> Dict[str, jax.Array]:
     """Routed expert ``e`` of one layer."""
     kg, ku, kd = jax.random.split(
-        jax.random.fold_in(jax.random.fold_in(lk, _TAG["experts"]), e), 3)
+        jax.random.fold_in(jax.random.fold_in(lk, TAG["experts"]), e), 3)
     return {"w_gate": _matrix(kg, a.d_model, a.d_expert),
             "w_up": _matrix(ku, a.d_model, a.d_expert),
             "w_down": _matrix(kd, a.d_expert, a.d_model)}
@@ -119,59 +105,27 @@ def slot_experts(num_experts: int, num_servers: int,
     return np.asarray(laid["w_gate"])[..., 0, 0].astype(np.int64) - 1
 
 
-def served_init(a: Arch, want, slots: np.ndarray):
-    """``key -> the program's parameter tree``, checked against ``want``.
-
-    ``want`` is the tree of shapes the program's own init would return
-    (``jax.eval_shape``); ``slots`` is the (S, L) expert-id layout of its
-    server slots.  A leaf this function does not know, or a shape or type
-    that differs, is an error: the layout changed under the benchmark.
-    """
-    n_dense = a.first_k_dense
+def server_banks(lk: jax.Array, a, slots: np.ndarray) -> Dict[str, jax.Array]:
+    """One MoE layer's routed experts as the program's server slots hold
+    them: (S, L, ...) each, expert ``slots[s, j]`` in slot ``j`` of server
+    ``s`` and zeros in an empty slot."""
     flat_slots = jnp.asarray(np.maximum(slots, 0).reshape(-1))
     held = jnp.asarray((slots >= 0).reshape(-1))
     S, L = slots.shape
 
-    def dense_layer(k, layer):
-        lk = layer_key(k, layer)
-        w = attention(lk, a)
-        return {"ln1": w["ln1"], "ln2": w["ln2"],
-                "attn": {n: w[n] for n in ("wq", "wk", "wv", "wo")},
-                "mlp": ffn(lk, a, a.d_ff)}
+    def slot(inp):
+        e, h = inp
+        return {n: jnp.where(h, v, 0) for n, v in expert(lk, a, e).items()}
+    # slot by slot, so one expert's draws are the only temporaries
+    experts = jax.lax.map(slot, (flat_slots, held))
+    return {n: v.reshape(S, L, *v.shape[1:]) for n, v in experts.items()}
 
-    def moe_layer(k, layer):
-        lk = layer_key(k, layer)
-        w = attention(lk, a)
-        def slot(inp):
-            e, h = inp
-            return {n: jnp.where(h, v, 0) for n, v in
-                    expert(lk, a, e).items()}
-        # slot by slot, so one expert's draws are the only temporaries
-        experts = jax.lax.map(slot, (flat_slots, held))
-        servers = {n: v.reshape(S, L, *v.shape[1:])
-                   for n, v in experts.items()}
-        moe = {"router": {"w_router": router(lk, a)}, "servers": servers}
-        if a.num_shared_experts:
-            moe["shared"] = ffn(lk, a, a.d_expert * a.num_shared_experts)
-        return {"ln1": w["ln1"], "ln2": w["ln2"],
-                "attn": {n: w[n] for n in ("wq", "wk", "wv", "wo")},
-                "moe": moe}
 
-    vocab_rows = want["embed"].shape[0]
-
-    def init(k):
-        tree = {"embed": embed(k, a, vocab_rows),
-                "final_ln": final_norm(k, a),
-                "head": head(k, a, vocab_rows),
-                # one layer at a time, so the draws of one layer are the
-                # only temporaries beside the finished tree
-                "blocks": jax.lax.map(lambda l: moe_layer(k, l),
-                                      jnp.arange(n_dense, a.num_layers))}
-        if n_dense:
-            tree["dense_blocks"] = jax.lax.map(lambda l: dense_layer(k, l),
-                                               jnp.arange(n_dense))
-        return tree
-
+def checked(init, want):
+    """``init``, once the tree it draws matches ``want``, the tree of
+    shapes the program's own init would return (``jax.eval_shape``).  A
+    leaf the draw does not make, or a shape or type that differs, is an
+    error: the layout changed under the benchmark."""
     got = jax.eval_shape(init, jax.random.PRNGKey(0))
     if jax.tree.structure(got) != jax.tree.structure(want):
         raise ValueError("the program's parameter tree changed: "
@@ -186,11 +140,10 @@ def served_init(a: Arch, want, slots: np.ndarray):
     return init
 
 
-def served_params(a: Arch, seed: int, want, slots: np.ndarray):
-    """The program's parameter tree, drawn on the device in one jitted
-    call from the seed."""
+def on_device(init, seed: int):
+    """The tree ``init`` draws from the seed, in one jitted call on the
+    first device."""
     from jax.sharding import SingleDeviceSharding
 
-    return jax.jit(served_init(a, want, slots),
-                   out_shardings=SingleDeviceSharding(jax.devices()[0]))(
-                       base_key(seed))
+    return jax.jit(init, out_shardings=SingleDeviceSharding(
+        jax.devices()[0]))(base_key(seed))

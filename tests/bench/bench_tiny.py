@@ -6,7 +6,7 @@ short lengths."""
 
 import copy
 
-from bench.arch import load_config
+from bench.models import load_config
 
 DEVICE = {"platform": "cpu", "kind": "cpu", "count": 1}
 PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
@@ -50,9 +50,10 @@ def tiny_mix(kind: str = "reason") -> dict:
 
 def tiny_run(kind: str = "reason", seed: int = 11, seconds: float = 2.0,
              trace: bool = False, limit: float = 0.03, controls=(),
-             metrics=None):
+             metrics=None, cfg=None):
     """One whole run of the harness on the CPU at the tiny size (the
-    look for a chip is the only step left out)."""
+    look for a chip is the only step left out); ``cfg`` in place of
+    :func:`tiny_config`."""
     import time
 
     from bench import run
@@ -60,7 +61,7 @@ def tiny_run(kind: str = "reason", seed: int = 11, seconds: float = 2.0,
     name = "mx.reason" if kind == "reason" else "mx.chat"
     bm = run.load_benchmark()
     return run.run_cell(
-        {"name": name, "chips": 1}, tiny_config(limit), tiny_mix(kind),
+        {"name": name, "chips": 1}, cfg or tiny_config(limit), tiny_mix(kind),
         seed=seed, seconds=seconds, trace=trace,
         metric_names=metrics or run.metrics_of(
             bm, name, "per_layer" if trace else "end_to_end"),

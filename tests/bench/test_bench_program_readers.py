@@ -2,13 +2,12 @@
 stage tag, from a reduced trace worked by hand, and host time per engine
 step, from spans planted in ``repro.tracing``'s ring."""
 
-import re
-
+import numpy as np
 import pytest
 
 from bench import run, stages
 from bench.drive import Record, Step
-from bench.metrics.gemm_roofline import KERNEL
+from bench.models import mixtral
 from bench.trace import TEXT_CHARS
 from repro import tracing
 
@@ -56,7 +55,7 @@ def _ctx(ops=OPS, steps=STEPS):
     rec.steps.append(Step(9.95, 9.96))                 # idle: no kind
     reduced = {"op_seconds": {n: SECONDS[n] for n in ops},
                "op_text": dict(ops)}
-    return run.Context(rec, None, {}, reduced)
+    return run.Context(rec, None, {}, reduced, None)
 
 
 def test_stage_of_each_op():
@@ -69,7 +68,43 @@ def test_stage_of_each_op():
 
 
 def test_the_tagged_kernel_keeps_its_roofline_signature():
-    assert re.search(KERNEL, OPS["%gemm"])
+    # the roofline's kernel is the op whose own kernel_metadata carries the
+    # gemm tag: not the server's slot copies, and not an XLA op that lies
+    # in the gemm stage's region (the row pad around the kernel)
+    pad = ('%fusion.12 = bf16[512,4096]{1,0} fusion(%x), '
+           'frontend_attributes={eaas_stage="gemm"}')
+    reduced = {"op_seconds": dict(SECONDS, **{"%pad": 0.6}),
+               "op_text": dict(OPS, **{"%pad": pad})}
+    assert stages.stage_of(pad) == "gemm"
+    assert stages.kernel_seconds(reduced, "gemm") == SECONDS["%gemm"]
+    assert stages.kernel_seconds(reduced, "server") is None
+
+
+def test_gemm_roofline_by_hand():
+    a = mixtral.Arch(
+        name="t", d_model=1024, num_layers=2, first_k_dense=0, num_heads=8,
+        num_kv_heads=2, head_dim=128, d_ff=2048, d_expert=2048,
+        num_experts=4, top_k=2, num_shared_experts=0, vocab=512,
+        rope_theta=1e4, rms_norm_eps=1e-6, score_fn="softmax",
+        normalize_topk=True)
+    ctx = _ctx()
+    ctx.arch, ctx.family = a, mixtral
+    ctx.peaks = {"bf16_flops_per_s": 1e14, "hbm_bytes_per_s": 1e12}
+    # 10 decode steps of 32 rows over 3 of the 4 experts (summed over the
+    # 2 layers); the idle step and a step past the close carry no load
+    for s in ctx.rec.steps[:STEPS]:
+        s.expert_load = np.array([20, 0, 24, 20])
+    ctx.rec.steps.append(Step(10.0, 10.5, "decode", 0.5,
+                              expert_load=np.array([64, 0, 0, 0])))
+    d, f = 1024, 2048
+    ops = 64 * 3 * 2 * d * f
+    nbytes = 3 * 2 * 3 * d * f * 2 + 64 * 3 * (d + f) * 2
+    least = STEPS * max(ops / 1e14, nbytes / 1e12)
+    assert run.read_metric("gemm_roofline", ctx) == pytest.approx(
+        100 * least / SECONDS["%gemm"])
+    ctx.trace = {"op_seconds": {"%slots": 0.5},
+                 "op_text": {"%slots": OPS["%slots"]}}
+    assert run.read_metric("gemm_roofline", ctx) is None
 
 
 @pytest.mark.parametrize("name, want", [
@@ -119,9 +154,10 @@ def test_step_host_ms_on_planted_spans(monkeypatch):
         ("decode", t0 + 99.99, [(t0 + 100.0, t0 + 100.49)], t0 + 100.5),
         ("decode", t0 + 100.5, [], t0 + 100.6),           # after the window
     ])
-    ctx = run.Context(Record(t0=t0, t1=t0 + 100), None, {}, None)
+    ctx = run.Context(Record(t0=t0, t1=t0 + 100), None, {}, None, None)
     # 6, 12 and 20 ms: the last step starts in the window and ends past it
     assert run.read_metric("step_host_ms", ctx) == pytest.approx(
         (6 + 12 + 20) / 3)
-    empty = run.Context(Record(t0=t0 + 200, t1=t0 + 300), None, {}, None)
+    empty = run.Context(Record(t0=t0 + 200, t1=t0 + 300), None, {}, None,
+                        None)
     assert run.read_metric("step_host_ms", empty) is None

@@ -20,7 +20,7 @@ def _ctx():
                        2: [6.0, 7.0, 8.0, 9.0, 10.0]}
     rec.compiles_before = {"compiles": 3, "cache_hits": 2}
     rec.compiles_after = {"compiles": 3, "cache_hits": 3}
-    return run.Context(rec, None, {}, None)
+    return run.Context(rec, None, {}, None, None)
 
 
 @pytest.mark.parametrize("name, want", [

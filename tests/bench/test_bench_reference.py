@@ -14,8 +14,8 @@ percentile."""
 import numpy as np
 import pytest
 
-from bench import reference, system, weights
-from bench.arch import arch_of
+from bench import system, weights
+from bench.models import mixtral
 from bench_tiny import tiny_config
 
 SEED = 2 ** 31 + 5
@@ -35,7 +35,7 @@ def _variant(name: str) -> dict:
 @pytest.fixture(scope="module", params=["mixtral", "dense_shared"])
 def served(request):
     cfg = _variant(request.param)
-    a = arch_of(cfg)
+    a = mixtral.arch(cfg)
     cluster, eng, _, _ = system.build(cfg, a, SEED)
     return a, eng.executor
 
@@ -61,7 +61,7 @@ def test_prefill_then_decode_match_the_reference(served):
                                      is_last=s + 32 >= len(p))
         toks.append([int(np.asarray(lg)[0, :a.vocab].argmax())])
         first = np.asarray(lg)[0, :a.vocab]
-        ref = reference.forward(a, SEED, [p], [np.array([len(p) - 1])])[0][0]
+        ref = mixtral.forward(a, SEED, [p], [np.array([len(p) - 1])])[0][0]
         errs.append(_rel(first, ref))
     steps = 4
     got = [[] for _ in range(B)]
@@ -76,7 +76,7 @@ def test_prefill_then_decode_match_the_reference(served):
     seqs = [np.concatenate([p, np.asarray(tk[:-1], np.int32)])
             for p, tk in zip(prompts, toks)]
     reads = [np.arange(len(p), len(p) + steps) for p in prompts]
-    ref = reference.forward(a, SEED, seqs, reads)
+    ref = mixtral.forward(a, SEED, seqs, reads)
     for b in range(B):
         errs.extend(_rel_rows(np.stack(got[b]), ref[b]))
     assert np.percentile(errs, 90) < REL_TOL
@@ -103,14 +103,14 @@ def test_weights_are_a_function_of_the_seed(served):
 
 
 def test_lower_precision_moves_the_reference():
-    a = arch_of(tiny_config())
+    a = mixtral.arch(tiny_config())
     rng = np.random.default_rng(4)
     seq = rng.integers(0, a.vocab, 40).astype(np.int32)
     reads = [np.arange(8, 40)]
-    f32 = reference.forward(a, 9, [seq], reads)[0]
-    again = reference.forward(a, 9, [seq], reads)[0]
+    f32 = mixtral.forward(a, 9, [seq], reads)[0]
+    again = mixtral.forward(a, 9, [seq], reads)[0]
     np.testing.assert_array_equal(f32, again)
-    gaps = {q: _rel(reference.forward(a, 9, [seq], reads, quant=q)[0], f32)
+    gaps = {q: _rel(mixtral.forward(a, 9, [seq], reads, quant=q)[0], f32)
             for q in ("int8", "fp8")}
     assert 0 < gaps["int8"] < gaps["fp8"]
 

@@ -8,7 +8,7 @@ from pathlib import Path
 
 import pytest
 
-from bench.arch import arch_of, load_config
+from bench.models import family, load_config
 
 ROOT = Path(__file__).resolve().parents[2]
 BM = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -36,8 +36,9 @@ def test_config_file(c):
     for key, cut in cfg["reduced"].items():
         assert cfg[key] == cut["here"] != cut["published"]
         assert not key.endswith(("_dim", "_rank")) and key not in WIDTHS
-    a = arch_of(cfg)
-    assert a.num_heads % a.num_kv_heads == 0
+    # the family refuses what it cannot compute as the file states it
+    a = family(cfg).arch(cfg)
+    assert a.name == c["name"] and a.moe_layers > 0
     assert a.num_experts % cfg["serving"]["num_servers"] == 0
     assert cfg["check"] and all(v > 0 for v in cfg["check"].values())
 

@@ -7,7 +7,16 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import trace
+from bench import stages, trace
+
+# the Pallas grouped GEMM of the recorded traces by its operands: a
+# tpu_custom_call on the two scalar-prefetch tile tables, the sorted rows
+# and the (groups, K, N) weight bank
+SIGNATURE = (r'custom-call\(s32\[\d+\]\{[^}]*\} %[\w.-]+, '
+             r's32\[\d+\]\{[^}]*\} %[\w.-]+, '
+             r'bf16\[\d+,\d+\]\{[^}]*\} %[\w.-]+, '
+             r'bf16\[\d+,\d+,\d+\]\{[^}]*\} %[\w.-]+\), '
+             r'custom_call_target="tpu_custom_call"')
 
 
 def _ev(device, host):
@@ -76,12 +85,12 @@ def test_extract_from_a_cpu_trace(tmp_path):
     assert r["device_ops"]
 
 
-def _recorded():
+def _recorded(cell="r1.reason"):
     import gzip
     import json
     from pathlib import Path
 
-    path = Path(__file__).with_name("data") / "r1.reason.trace.json.gz"
+    path = Path(__file__).with_name("data") / f"{cell}.trace.json.gz"
     with gzip.open(path, "rt") as f:
         return json.load(f)
 
@@ -89,9 +98,6 @@ def _recorded():
 def test_recorded_chip_trace():
     """0.4 s of an r1.reason window on one TPU v5e (the events as
     :func:`trace.extract` keeps them), reduced."""
-    import importlib.util
-    from pathlib import Path
-
     r = trace.reduce(_recorded())
     assert r["window_s"] == pytest.approx(0.4)
     assert r["busy_s"] == pytest.approx(0.384330765)
@@ -100,10 +106,29 @@ def test_recorded_chip_trace():
     # so the grouped GEMM's three calls lead
     assert all("custom-call" in n for n, _ in r["device_ops"][:3])
     assert not any(n.startswith("%while") for n, _ in r["device_ops"][:5])
-    spec = importlib.util.spec_from_file_location(
-        "gemm_roofline", Path(trace.__file__).with_name("metrics")
-        / "gemm_roofline.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert trace.op_seconds(r, mod.KERNEL) == pytest.approx(0.214620911)
+    assert trace.op_seconds(r, SIGNATURE) == pytest.approx(0.214620911)
+    # the trace predates the kernel's own tag (kernel_metadata={}): the
+    # roofline finds no kernel in it, and reads nothing
+    assert stages.kernel_seconds(r, "gemm") is None
     assert dict(r["idle_gaps"])["bench.step"] == pytest.approx(0.013801973)
+
+
+def test_recorded_kernel_by_its_own_tag():
+    """0.4 s of an mx.reason window on one TPU v5e, from a program that
+    tags its kernels: the roofline's selection by the kernel's own tag
+    picks exactly the ops the operand signature picks, its three grouped
+    GEMM calls."""
+    import re
+
+    r = trace.reduce(_recorded("mx.reason"))
+    assert r["window_s"] == pytest.approx(0.4)
+    assert r["busy_s"] == pytest.approx(0.370164085)
+    text = r["op_text"]
+    tagged = {n for n in r["op_seconds"]
+              if (m := stages._KERNEL_TAG.search(text[n]))
+              and m.group(1) == "gemm"}
+    signed = {n for n in r["op_seconds"] if re.search(SIGNATURE, text[n])}
+    assert tagged == signed == {"%closed_call.42", "%closed_call.43",
+                                "%closed_call.44"}
+    assert stages.kernel_seconds(r, "gemm") == pytest.approx(0.056509534)
+    assert trace.op_seconds(r, SIGNATURE) == pytest.approx(0.056509534)
