@@ -8,10 +8,12 @@ One chip, in one process:
 
 1. device report;
 2. the ``repro.launch.serve`` path (its own ``build``/``submit``/``run``)
-   at the ``deepseek-r1-chip`` cut: ``Cluster``, 1 client, ``mode="eaas"``,
-   4 expert servers with 2 redundant slots each, lockstep, dense KV cache,
-   wall clock.  It serves 8 seeded requests (prompts of 64-256 tokens, 32
-   new tokens each) and kills expert server 1 mid-decode.  Checked: every
+   at the ``deepseek-r1-chip`` cut (latent attention, 3 dense + 4 MoE
+   layers, 16 of 256 routed experts held): ``Cluster``, 1 client,
+   ``mode="eaas"``, 4 expert servers with 2 redundant slots each,
+   lockstep, dense latent cache, wall clock.  It serves 8 seeded requests
+   (prompts of 64-256 tokens, 32 new tokens each) and kills expert server
+   1 mid-decode.  Checked: every
    request got its tokens, every logit is finite, the failure recompiled
    nothing and stalled no step;
 3. one MoE layer at the same widths against the float32 reference
@@ -90,13 +92,14 @@ def require_tpu() -> dict:
 
 
 def compiles_line() -> str:
-    """The process's compiles so far, dropped rows and grouped GEMM row
-    tiles, from :mod:`repro.tracing`'s counters."""
+    """The process's compiles so far, routes to held experts, dropped rows
+    and grouped GEMM row tiles, from :mod:`repro.tracing`'s counters."""
     c = tracing.counters()
     return (f"backend compiles {c.get('jax.compiles', 0):.0f}, "
             f"{c.get('jax.compile_s', 0.0):.1f} s; persistent-cache hits "
             f"{c.get('jax.cache_hits', 0):.0f}; inside engine steps "
-            f"{c.get('engine.step_compiles', 0):.0f}; dropped rows "
+            f"{c.get('engine.step_compiles', 0):.0f}; routes to held "
+            f"experts {c.get('moe.routes_held', 0):.0f}; dropped rows "
             f"{c.get('moe.dropped_rows', 0):.0f}; grouped GEMM row tiles "
             f"live {c.get('gemm.tiles_live', 0):.0f} of "
             f"{c.get('gemm.tiles', 0):.0f}")
@@ -133,11 +136,13 @@ def serve_phase(reduced: bool = False, seed: int = 0, max_seq: int = 2048,
     cfg, system, scaler = serve.build(args)
     eng = system.clients[0]
     jax.block_until_ready(eng.executor.params)
-    log(f"serve: built {cfg.arch_id} (d_model {cfg.d_model}, "
-        f"{cfg.num_heads}x{cfg.head_dim} heads, kv {cfg.num_kv_heads}, "
-        f"{cfg.moe.num_experts} experts top-{cfg.moe.top_k}, d_expert "
-        f"{cfg.moe.d_expert}, vocab {cfg.vocab_size}) in "
-        f"{time.perf_counter() - t0:.1f} s")
+    heads = (f"{cfg.num_heads} latent-attention heads, kv_lora_rank "
+             f"{cfg.mla.kv_lora_rank}" if cfg.mla else
+             f"{cfg.num_heads}x{cfg.head_dim} heads, kv {cfg.num_kv_heads}")
+    log(f"serve: built {cfg.arch_id} (d_model {cfg.d_model}, {heads}, "
+        f"{cfg.moe.num_experts} of {cfg.moe.router_width} experts top-"
+        f"{cfg.moe.top_k}, d_expert {cfg.moe.d_expert}, vocab "
+        f"{cfg.vocab_size}) in {time.perf_counter() - t0:.1f} s")
     reqs = serve.submit(system, cfg, args, scaler)
     prefill_steps = sum(-(-len(r.prompt) // args.prefill_chunk)
                         for r in reqs)

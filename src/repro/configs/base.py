@@ -32,6 +32,73 @@ class MoEConfig:
     capacity_factor: float = 1.25       # per-(client,server) buffer headroom
     router_aux_loss_coef: float = 0.001
     router_z_loss_coef: float = 0.0
+    # group-limited top-k (DeepSeek-V3 ``noaux_tc``): the routed experts
+    # fall into n_group equal groups, a group scores the sum of its top-2
+    # selection scores, and top_k is taken inside the topk_group best
+    # groups.  n_group 1 is plain top-k.
+    n_group: int = 1
+    topk_group: int = 1
+    # a learned (E,) bias added to the scores for selection only; the
+    # weights stay the unbiased scores
+    correction_bias: bool = False
+    routed_scaling_factor: float = 1.0    # multiplies the routed weights
+    # the chip's share under expert parallelism: the router scores
+    # ``routed_experts`` (0: num_experts) and this layer holds the
+    # num_experts of them from global id ``first_expert`` on
+    routed_experts: int = 0
+    first_expert: int = 0
+
+    @property
+    def router_width(self) -> int:
+        """Experts the router scores: the published count."""
+        return self.routed_experts or self.num_experts
+
+    @property
+    def holds_all(self) -> bool:
+        return self.router_width == self.num_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): queries through a
+    low-rank ``q_lora_rank`` bottleneck, keys and values up-projected from
+    a cached ``kv_lora_rank`` latent, and a ``qk_rope_head_dim`` rotary
+    key shared by every head."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def num_params(self, d_model: int, num_heads: int) -> int:
+        """The five projections and the two latent norms."""
+        q, kv, h = self.q_lora_rank, self.kv_lora_rank, num_heads
+        return (d_model * q + q + q * h * self.qk_head_dim
+                + d_model * (kv + self.qk_rope_head_dim) + kv
+                + kv * h * (self.qk_nope_head_dim + self.v_head_dim)
+                + h * self.v_head_dim * d_model)
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (arXiv:2309.00071, as DeepSeek-V3 configures it):
+    ``factor`` stretches the low frequencies, a linear ramp between the
+    correction dims of ``beta_fast`` and ``beta_slow`` rotations over
+    ``original_max_position`` blends them with the unscaled ones, and the
+    softmax scale grows by ``mscale(factor, mscale_all_dim)**2``.  The
+    rotary tables' own temperature, ``mscale / mscale_all_dim`` of the
+    published config, is 1 for DeepSeek-V2 and V3 and is not kept."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -72,6 +139,8 @@ class ModelConfig:
     # --- family sub-configs -----------------------------------------------
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    mla: Optional[MLAConfig] = None             # latent attention (no GQA)
+    rope_scaling: Optional[YarnScaling] = None
 
     # hybrid (zamba2): a shared attention block is interleaved every
     # `shared_block_every` mamba layers.
@@ -129,7 +198,8 @@ class ModelConfig:
             self.head_dim, self.d_ff, self.vocab_size,
         )
         emb = v * d if self.tie_embeddings else 2 * v * d
-        attn = d * (h * dh) + 2 * d * (kv * dh) + (h * dh) * d
+        attn = (self.mla.num_params(d, h) if self.mla is not None
+                else d * (h * dh) + 2 * d * (kv * dh) + (h * dh) * d)
         ffn_dense = 3 * d * ff if self.activation == "swiglu" else 2 * d * ff
         total = emb
         n_layers = self.num_layers
@@ -157,7 +227,9 @@ class ModelConfig:
         if self.moe is not None:
             m = self.moe
             expert_ffn = (3 if self.activation == "swiglu" else 2) * d * m.d_expert
-            per_moe = attn + m.num_experts * expert_ffn + d * m.num_experts
+            per_moe = attn + m.num_experts * expert_ffn + d * m.router_width
+            if m.correction_bias:
+                per_moe += m.router_width
             per_moe += m.num_shared_experts * expert_ffn
             if m.dense_residual:
                 per_moe += ffn_dense
@@ -171,13 +243,14 @@ class ModelConfig:
         return total
 
     def num_active_params(self) -> int:
-        """Parameters touched per token (MoE: only routed experts)."""
+        """Parameters touched per token (MoE: only routed experts; of a
+        chip's share, the top-k it would run if it held every expert)."""
         if self.moe is None:
             return self.num_params()
         m = self.moe
         d, ff = self.d_model, m.d_expert
         expert_ffn = (3 if self.activation == "swiglu" else 2) * d * ff
-        inactive = (m.num_experts - m.top_k) * expert_ffn
+        inactive = max(m.num_experts - m.top_k, 0) * expert_ffn
         return self.num_params() - len(self.moe_layer_ids()) * inactive
 
     # ------------------------------------------------------------- reduced
@@ -211,6 +284,9 @@ class ModelConfig:
             source=self.source,
         )
         if self.moe is not None:
+            # every expert held; group-limited routing keeps its groups
+            # (4 of 2 experts, the best 2 kept)
+            grouped = self.moe.n_group > 1
             kw["moe"] = dataclasses.replace(
                 self.moe,
                 num_experts=8,
@@ -218,7 +294,17 @@ class ModelConfig:
                 d_expert=128,
                 num_shared_experts=min(self.moe.num_shared_experts, 1),
                 first_k_dense=min(self.moe.first_k_dense, 1),
+                n_group=4 if grouped else 1,
+                topk_group=2 if grouped else 1,
+                routed_experts=0,
+                first_expert=0,
             )
+        if self.mla is not None:
+            kw["mla"] = MLAConfig(q_lora_rank=64, kv_lora_rank=32,
+                                  qk_nope_head_dim=16, qk_rope_head_dim=8,
+                                  v_head_dim=16)
+            kw["num_kv_heads"] = kw["num_heads"]
+        kw["rope_scaling"] = self.rope_scaling
         if self.ssm is not None:
             kw["ssm"] = dataclasses.replace(
                 self.ssm, d_state=16, head_dim=32, num_ssm_heads=4)

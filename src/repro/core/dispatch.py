@@ -12,7 +12,10 @@ Two packing algorithms (both produce identical buffers):
 
 Capacity semantics follow the paper's fixed-size buffer slots: at most
 ``capacity`` tokens per (client, server) pair per layer; overflow tokens are
-dropped (counted) exactly as capacity-factor MoE implementations do.
+dropped (counted) exactly as capacity-factor MoE implementations do.  A
+route to an expert the layer does not hold (``held`` False: a chip's share
+under expert parallelism) is sent nowhere; it takes no slot and is not a
+drop.
 """
 
 from __future__ import annotations
@@ -26,10 +29,11 @@ from repro.core.types import DispatchBuffers
 
 def pack(x: jax.Array, expert_ids: jax.Array, scores: jax.Array,
          server_ids: jax.Array, num_servers: int, capacity: int,
-         method: str = "onehot") -> DispatchBuffers:
+         method: str = "onehot", held=None) -> DispatchBuffers:
     """Build request buffers for every destination server.
 
-    x: (T, d); expert_ids/scores/server_ids: (T, k).
+    x: (T, d); expert_ids/scores/server_ids: (T, k); held: (T, k) bool or
+    None (every route held).
     """
     T, d = x.shape
     k = expert_ids.shape[1]
@@ -40,6 +44,9 @@ def pack(x: jax.Array, expert_ids: jax.Array, scores: jax.Array,
     flat_expert = expert_ids.reshape(Tk)
     flat_score = scores.reshape(Tk)
     flat_token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+    if held is not None:
+        # the server past the last: counted nowhere, given no slot
+        flat_server = jnp.where(held.reshape(Tk), flat_server, S)
 
     if method == "sort":
         order = jnp.argsort(flat_server, stable=True)
@@ -60,6 +67,8 @@ def pack(x: jax.Array, expert_ids: jax.Array, scores: jax.Array,
         raise ValueError(method)
 
     valid = slot < C
+    if held is not None:
+        valid &= held.reshape(Tk)
     flat_idx = jnp.where(valid, flat_server * C + slot, S * C)     # OOB drops
 
     hidden = jnp.zeros((S * C, d), x.dtype).at[flat_idx].set(

@@ -45,9 +45,10 @@ class ServerPool:
     route_bias: np.ndarray = None
 
     def __post_init__(self):
-        E = self.cfg.moe.num_experts
+        E = self.cfg.moe.num_experts          # the experts held
         self.stats = load_balance.ExpertStats(E)
-        self.route_bias = np.zeros(E, np.float32)
+        # router-logit offsets span every routed expert, held or not
+        self.route_bias = np.zeros(self.cfg.moe.router_width, np.float32)
         # scale-to-zero state: experts paged out of the tier (replica slots
         # evicted; the primary shard stays addressable as the page-in
         # source) and, for hysteresis, when each one last paged back in

@@ -91,7 +91,8 @@ def init_eaas_moe(key, cfg: ModelConfig, num_servers: int,
     server_w = expert_server.build_server_weights(
         bank, num_servers, redundant_table)
     params = {
-        "router": router.init_router(ks[1], cfg.d_model, m.num_experts),
+        "router": router.init_router(ks[1], cfg.d_model, m.router_width,
+                                     m.correction_bias),
         "servers": server_w,
     }
     dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
@@ -144,12 +145,28 @@ def _client_extras(params: Dict, x: jax.Array, cfg_moe: MoEConfig,
         return extra
 
 
+def _share(expert_ids: jax.Array, cfg_moe: MoEConfig):
+    """(ids among the experts held, which routes are held) of global
+    routes (T, k); the mask is None when the layer holds every expert."""
+    if cfg_moe.holds_all:
+        return expert_ids, None
+    local = expert_ids - cfg_moe.first_expert
+    held = (local >= 0) & (local < cfg_moe.num_experts)
+    return jnp.clip(local, 0, cfg_moe.num_experts - 1), held
+
+
 def eaas_moe_apply(params: Dict, x: jax.Array, cfg_moe: MoEConfig,
                    runtime: MoERuntime, *, activation: str = "swiglu",
                    axis_name: Optional[str] = None, mode: str = "local",
                    token_salt: Optional[jax.Array] = None,
                    ) -> Tuple[jax.Array, MoEStats]:
     """Apply the EAAS MoE layer to x: (T, d) -> (T, d).
+
+    A layer that holds a share of the routed experts (``cfg_moe``'s
+    ``first_expert`` .. + ``num_experts`` of ``router_width``) routes over
+    all of them, sends only the routes to its own experts, and returns
+    their part of the result (plus the client's dense extras); its expert
+    load counts the held routes, by held expert.
 
     In SPMD modes this must be called inside shard_map with ``axis_name``
     bound to the server mesh axis; params["servers"] arrays then hold only
@@ -168,18 +185,22 @@ def eaas_moe_apply(params: Dict, x: jax.Array, cfg_moe: MoEConfig,
     with tracing.stage("router"):
         r = router.route(params["router"], x, cfg_moe,
                          bias=runtime.route_bias)
-        expert_load = router.expert_load(r.expert_ids, cfg_moe.num_experts)
+        expert_ids, held = _share(r.expert_ids, cfg_moe)
+        expert_load = router.expert_load(
+            expert_ids if held is None
+            else jnp.where(held, expert_ids, cfg_moe.num_experts),
+            cfg_moe.num_experts)
     with tracing.stage("dispatch"):
         if token_salt is None:
             token_salt = jnp.arange(T, dtype=jnp.int32)[:, None] + jnp.arange(
-                r.expert_ids.shape[1], dtype=jnp.int32)[None, :]
+                expert_ids.shape[1], dtype=jnp.int32)[None, :]
         server_ids = emap.lookup(runtime.mapping, runtime.alive,
-                                 r.expert_ids, token_salt,
+                                 expert_ids, token_salt,
                                  weights=runtime.replica_weights)
 
         # ---- client: pack buffer slots (paper §3.2) ----------------------
-        buffers = dispatch.pack(x, r.expert_ids, r.scores, server_ids, S, C,
-                                method=runtime.dispatch_method)
+        buffers = dispatch.pack(x, expert_ids, r.scores, server_ids, S, C,
+                                method=runtime.dispatch_method, held=held)
         hid, eid, sc, cnt = comm.send_to_servers(buffers, axis_name, mode)
 
     # ---- server compute ----------------------------------------------------

@@ -1,10 +1,13 @@
 """Plain float32 reference of one EAAS MoE layer.
 
-No routing table, buffers, servers or kernels: every expert is applied to
-every token and weighted by the router's normalised top-k scores, then the
-shared experts are added.  It reads the expert bank from the per-server
-primaries of an :func:`repro.core.moe_layer.init_eaas_moe` params tree, so
-the layer under test and the reference see the same weights.
+No routing table, buffers, servers or kernels: every held expert is applied
+to every token and weighted by the router's normalised top-k scores (its
+selection, group-limited where the config says so, is the router's own
+:func:`repro.core.router.select`), then the shared experts are added.  A
+layer that holds a share of the routed experts gives their part only.  It
+reads the expert bank from the per-server primaries of an
+:func:`repro.core.moe_layer.init_eaas_moe` params tree, so the layer under
+test and the reference see the same weights.
 
 On a TPU a float32 matmul runs in bf16 passes unless told otherwise, so
 callers there wrap it in ``jax.default_matmul_precision("highest")``.
@@ -18,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MoEConfig
+from repro.core.router import select
 
 
 def moe_layer_ref(params: Dict, x: jax.Array, cfg_moe: MoEConfig,
@@ -34,11 +38,14 @@ def moe_layer_ref(params: Dict, x: jax.Array, cfg_moe: MoEConfig,
         probs = jax.nn.sigmoid(logits)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
-    scores, ids = jax.lax.top_k(probs, cfg_moe.top_k)
+    ids, scores = select(probs, cfg_moe,
+                         params["router"].get("correction_bias"))
     if cfg_moe.normalize_topk:
         scores = scores / jnp.sum(scores, axis=-1, keepdims=True)
-    gate = jnp.zeros((T, E), jnp.float32).at[
+    scores = scores * cfg_moe.routed_scaling_factor
+    gate = jnp.zeros((T, cfg_moe.router_width), jnp.float32).at[
         jnp.arange(T)[:, None], ids].set(scores)
+    gate = gate[:, cfg_moe.first_expert:cfg_moe.first_expert + E]
 
     def bank(w):                       # (S, L, a, b) primaries -> (E, a, b)
         S = w.shape[0]

@@ -193,8 +193,10 @@ def build_cell(arch: str, shape: InputShape, mesh, cfg=None,
     zero3 = train_phase_for(cfg.num_params(), mesh.shape["model"]) == "train"
     # decode: slot-shard the KV cache — over (data+model) for batch-1 long
     # context, over model otherwise (attention weights replicated; see
-    # sharding_rules phase "decode" and EXPERIMENTS.md §Perf iter 1)
-    seq_shard = shape.kind == "decode"
+    # sharding_rules phase "decode" and EXPERIMENTS.md §Perf iter 1).
+    # Latent attention has no slot-sharded decode: it decodes on the plain
+    # path, which the compiler partitions from the cache's sharding.
+    seq_shard = shape.kind == "decode" and cfg.mla is None
     seq_axes = ()
     if seq_shard:
         seq_axes = (*dp, "model") if shape.global_batch == 1 else ("model",)

@@ -7,7 +7,9 @@ RoPE is applied to q/k *before* the keys are cached, so cached keys are
 already rotated (standard practice; makes ring-buffer windows trivial).
 What the public functions here trace (projections, rope, the cache write,
 the attention itself and the output projection) carries the ``attention``
-stage tag (:func:`repro.tracing.stage`).
+stage tag (:func:`repro.tracing.stage`).  A config with ``mla`` set runs
+latent attention instead (:mod:`repro.models.mla`, stage ``mla``) through
+the same four entry points: init, full, chunk and decode.
 """
 
 from __future__ import annotations
@@ -21,6 +23,7 @@ import jax.numpy as jnp
 from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.models import kv_cache as kvc
+from repro.models import mla
 from repro.models.common import dense_init, softcap
 from repro.models.rope import apply_rope, mrope_cos_sin, rope_cos_sin, text_mrope_positions
 
@@ -30,6 +33,8 @@ NEG_INF = -1e30
 # --------------------------------------------------------------------- init
 
 def init_attention(key, cfg: ModelConfig, cross: bool = False) -> Dict:
+    if cfg.mla is not None and not cross:
+        return mla.init_mla(key, cfg)
     d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     ks = jax.random.split(key, 4)
@@ -94,6 +99,9 @@ def full_attention(params: Dict, cfg: ModelConfig, x: jax.Array,
     Long sequences run the chunked path: a scan over q blocks, each block
     rematerialized in backward (flash-attention memory profile).
     """
+    if cfg.mla is not None:
+        return mla.full_attention(params, cfg, x, positions,
+                                  return_kv=return_kv)
     with tracing.stage("attention"):
         b, s, d = x.shape
         h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -177,6 +185,8 @@ def chunk_attention(params: Dict, cfg: ModelConfig, x: jax.Array,
     prefix-cache blocks written by an *earlier* request, which is how a
     prefix hit lets the chunk start mid-prompt.
     """
+    if cfg.mla is not None:
+        return mla.chunk_attention(params, cfg, x, cache, positions)
     with tracing.stage("attention"):
         b, s, d = x.shape
         h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -220,6 +230,8 @@ def decode_attention(params: Dict, cfg: ModelConfig, x: jax.Array,
 
     Returns (output (batch, 1, d_model), updated cache).
     """
+    if cfg.mla is not None:
+        return mla.decode_attention(params, cfg, x, cache)
     with tracing.stage("attention"):
         b = x.shape[0]
         h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim

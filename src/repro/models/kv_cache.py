@@ -35,7 +35,8 @@ import jax.numpy as jnp
 @jax.tree_util.register_dataclass
 @dataclass
 class KVCache:
-    """One layer's cache.  k/v: (batch, slots, kv_heads, head_dim)."""
+    """One layer's cache.  k: (batch, slots, kv_heads, head_dim), v the
+    same with its own last dim."""
 
     k: jax.Array
     v: jax.Array
@@ -46,24 +47,26 @@ class KVCache:
 
 
 def init_kv_cache(batch: int, max_seq: int, kv_heads: int, head_dim: int,
-                  dtype=jnp.bfloat16, window: int = 0) -> KVCache:
+                  dtype=jnp.bfloat16, window: int = 0,
+                  v_head_dim: int = 0) -> KVCache:
     slots = min(window, max_seq) if window else max_seq
     return KVCache(
         k=jnp.zeros((batch, slots, kv_heads, head_dim), dtype),
-        v=jnp.zeros((batch, slots, kv_heads, head_dim), dtype),
+        v=jnp.zeros((batch, slots, kv_heads, v_head_dim or head_dim), dtype),
         length=jnp.zeros((batch,), jnp.int32),
         window=window,
     )
 
 
 def kv_cache_spec(batch: int, max_seq: int, kv_heads: int, head_dim: int,
-                  dtype=jnp.bfloat16, window: int = 0) -> KVCache:
+                  dtype=jnp.bfloat16, window: int = 0,
+                  v_head_dim: int = 0) -> KVCache:
     """ShapeDtypeStruct twin of :func:`init_kv_cache` (for the dry-run)."""
     slots = min(window, max_seq) if window else max_seq
     sds = jax.ShapeDtypeStruct
     return KVCache(
         k=sds((batch, slots, kv_heads, head_dim), dtype),
-        v=sds((batch, slots, kv_heads, head_dim), dtype),
+        v=sds((batch, slots, kv_heads, v_head_dim or head_dim), dtype),
         length=sds((batch,), jnp.int32),
         window=window,
     )
@@ -134,7 +137,8 @@ def valid_mask(cache: KVCache) -> jax.Array:
 class PagedKVCache:
     """One layer's block-pool cache.
 
-    k/v: (num_blocks, block_size, kv_heads, head_dim) — shared pool;
+    k/v: (num_blocks, block_size, kv_heads, head_dim) — shared pool (v
+    with its own last dim);
     block_tables: (batch, max_blocks) int32 — per-sequence block names
     (0 = unset, the reserved scratch block);
     length: (batch,) int32 — tokens written per sequence.
@@ -149,10 +153,12 @@ class PagedKVCache:
 
 def init_paged_kv_cache(num_blocks: int, block_size: int, batch: int,
                         max_blocks: int, kv_heads: int, head_dim: int,
-                        dtype=jnp.bfloat16) -> PagedKVCache:
+                        dtype=jnp.bfloat16,
+                        v_head_dim: int = 0) -> PagedKVCache:
+    vd = v_head_dim or head_dim
     return PagedKVCache(
         k=jnp.zeros((num_blocks, block_size, kv_heads, head_dim), dtype),
-        v=jnp.zeros((num_blocks, block_size, kv_heads, head_dim), dtype),
+        v=jnp.zeros((num_blocks, block_size, kv_heads, vd), dtype),
         block_tables=jnp.zeros((batch, max_blocks), jnp.int32),
         length=jnp.zeros((batch,), jnp.int32),
         block_size=block_size,
@@ -161,12 +167,14 @@ def init_paged_kv_cache(num_blocks: int, block_size: int, batch: int,
 
 def paged_kv_cache_spec(num_blocks: int, block_size: int, batch: int,
                         max_blocks: int, kv_heads: int, head_dim: int,
-                        dtype=jnp.bfloat16) -> PagedKVCache:
+                        dtype=jnp.bfloat16,
+                        v_head_dim: int = 0) -> PagedKVCache:
     """ShapeDtypeStruct twin of :func:`init_paged_kv_cache`."""
     sds = jax.ShapeDtypeStruct
+    vd = v_head_dim or head_dim
     return PagedKVCache(
         k=sds((num_blocks, block_size, kv_heads, head_dim), dtype),
-        v=sds((num_blocks, block_size, kv_heads, head_dim), dtype),
+        v=sds((num_blocks, block_size, kv_heads, vd), dtype),
         block_tables=sds((batch, max_blocks), jnp.int32),
         length=sds((batch,), jnp.int32),
         block_size=block_size,
@@ -224,9 +232,9 @@ def gather_blocks(cache: PagedKVCache):
     a dense :class:`KVCache` of ``max_blocks * block_size`` slots, so the
     downstream attention math is shared verbatim."""
     B, mb = cache.block_tables.shape
-    bs, kvh, hd = cache.k.shape[1], cache.k.shape[2], cache.k.shape[3]
-    kv = cache.k[cache.block_tables].reshape(B, mb * bs, kvh, hd)
-    vv = cache.v[cache.block_tables].reshape(B, mb * bs, kvh, hd)
+    bs, kvh = cache.k.shape[1], cache.k.shape[2]
+    kv = cache.k[cache.block_tables].reshape(B, mb * bs, kvh, -1)
+    vv = cache.v[cache.block_tables].reshape(B, mb * bs, kvh, -1)
     return kv, vv
 
 

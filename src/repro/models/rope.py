@@ -1,7 +1,9 @@
-"""Rotary position embeddings: standard RoPE and Qwen2-VL M-RoPE."""
+"""Rotary position embeddings: standard RoPE, YaRN-scaled RoPE
+(DeepSeek-V3) and Qwen2-VL M-RoPE."""
 
 from __future__ import annotations
 
+import math
 from typing import Tuple
 
 import jax
@@ -13,13 +15,42 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def rope_cos_sin(positions: jax.Array, head_dim: int, theta: float
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """cos/sin tables for integer positions.
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: ``0.1 * mscale * ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, scaling) -> jax.Array:
+    """YaRN inverse frequencies (DeepSeek-V3's reference code): a linear
+    ramp over the frequency index, 0 below the correction dim of
+    ``beta_fast`` rotations and 1 above that of ``beta_slow`` (over
+    ``original_max_position``), blends each plain frequency with it
+    divided by ``factor``."""
+    orig = scaling.original_max_position
+
+    def correction_dim(rotations):
+        return (head_dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    inv = rope_freqs(head_dim, theta)
+    return inv / scaling.factor * ramp + inv * (1.0 - ramp)
+
+
+def rope_cos_sin(positions: jax.Array, head_dim: int, theta: float,
+                 scaling=None) -> Tuple[jax.Array, jax.Array]:
+    """cos/sin tables for integer positions; ``scaling`` a
+    :class:`~repro.configs.base.YarnScaling` or None.
 
     positions: (..., seq) int32 -> cos,sin: (..., seq, head_dim//2) fp32
     """
-    inv = rope_freqs(head_dim, theta)
+    inv = (rope_freqs(head_dim, theta) if scaling is None
+           else yarn_freqs(head_dim, theta, scaling))
     ang = positions.astype(jnp.float32)[..., None] * inv
     return jnp.cos(ang), jnp.sin(ang)
 

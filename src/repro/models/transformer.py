@@ -104,7 +104,7 @@ def _moe_apply(params_moe: Dict, x2d: jax.Array, cfg: ModelConfig,
 
     routed = {k: params_moe[k] for k in ("router", "servers")}
     in_specs = (
-        {"router": {"w_router": _P(None, None)},
+        {"router": jax.tree.map(lambda _: _P(), params_moe["router"]),
          "servers": {"w_gate": _P(model_ax, None, None, None),
                      "w_up": _P(model_ax, None, None, None),
                      "w_down": _P(model_ax, None, None, None)}},
@@ -260,6 +260,10 @@ def _sp_decode_attention(params, cfg: ModelConfig, x: jax.Array,
     (acc, m, l) over its cache slice; one tiny psum combines.  The new
     token's k/v is written only by the owning shard (one-sided, local).
     """
+    if cfg.mla is not None:
+        raise ValueError("sequence-sharded decode splits a GQA cache's "
+                         "heads' slots; latent attention is not supported "
+                         "there")
     mesh = ctx.mesh
     if mesh is None:
         return attn.decode_attention(params, cfg, x, cache,
@@ -638,10 +642,12 @@ def _build_decoder(cfg: ModelConfig, num_servers: int,
                       "dropped": stats.dropped, "miss": stats.miss,
                       "expert_load": stats.expert_load}
 
+    kv_heads, k_dim, v_dim = _cache_dims(cfg)
+
     def init_cache(batch: int, max_slots: int, abstract: bool = False):
         def stack(n):
-            return _stack_kv_cache(n, batch, max_slots, cfg.num_kv_heads,
-                                   cfg.head_dim, dt, abstract=abstract)
+            return _stack_kv_cache(n, batch, max_slots, kv_heads, k_dim, dt,
+                                   abstract=abstract, v_head_dim=v_dim)
         cache = {"blocks": stack(n_main)}
         if n_dense_prefix:
             cache["dense"] = stack(n_dense_prefix)
@@ -657,7 +663,7 @@ def _build_decoder(cfg: ModelConfig, num_servers: int,
         def stack(n):
             return _stack_paged_kv_cache(
                 n, num_blocks, block_size, batch, max_slots // block_size,
-                cfg.num_kv_heads, cfg.head_dim, dt, abstract=abstract)
+                kv_heads, k_dim, dt, abstract=abstract, v_head_dim=v_dim)
         cache = {"blocks": stack(n_main)}
         if n_dense_prefix:
             cache["dense"] = stack(n_dense_prefix)
@@ -733,12 +739,22 @@ def _build_decoder(cfg: ModelConfig, num_servers: int,
                  cache_batch_axis=1, init_paged_cache=init_paged_cache)
 
 
+def _cache_dims(cfg: ModelConfig) -> Tuple[int, int, int]:
+    """(heads, k dim, v dim) of one layer's cache: latent attention keeps
+    one head of its normalised latent in k and of its rotary key in v."""
+    if cfg.mla is not None:
+        return 1, cfg.mla.kv_lora_rank, cfg.mla.qk_rope_head_dim
+    return cfg.num_kv_heads, cfg.head_dim, cfg.head_dim
+
+
 def _stack_kv_cache(n: int, batch: int, max_slots: int, kv_heads: int,
                     head_dim: int, dtype, *, window: int = 0,
-                    abstract: bool = False) -> kvc.KVCache:
+                    abstract: bool = False,
+                    v_head_dim: int = 0) -> kvc.KVCache:
     """A stacked (n, ...) KVCache for scan-over-layers stacks."""
     mk = kvc.kv_cache_spec if abstract else kvc.init_kv_cache
-    c = mk(batch, max_slots, kv_heads, head_dim, dtype, window=window)
+    c = mk(batch, max_slots, kv_heads, head_dim, dtype, window=window,
+           v_head_dim=v_head_dim)
     if abstract:
         lift = lambda a: jax.ShapeDtypeStruct((n,) + a.shape, a.dtype)
     else:
@@ -750,7 +766,8 @@ def _stack_kv_cache(n: int, batch: int, max_slots: int, kv_heads: int,
 def _stack_paged_kv_cache(n: int, num_blocks: int, block_size: int,
                           batch: int, max_blocks: int, kv_heads: int,
                           head_dim: int, dtype, *,
-                          abstract: bool = False) -> kvc.PagedKVCache:
+                          abstract: bool = False,
+                          v_head_dim: int = 0) -> kvc.PagedKVCache:
     """A stacked (n, ...) PagedKVCache for scan-over-layers stacks.
 
     Block tables / lengths are broadcast per layer so every leaf carries the
@@ -758,7 +775,7 @@ def _stack_paged_kv_cache(n: int, num_blocks: int, block_size: int,
     host-side pool each step."""
     mk = kvc.paged_kv_cache_spec if abstract else kvc.init_paged_kv_cache
     c = mk(num_blocks, block_size, batch, max_blocks, kv_heads, head_dim,
-           dtype)
+           dtype, v_head_dim=v_head_dim)
     if abstract:
         lift = lambda a: jax.ShapeDtypeStruct((n,) + a.shape, a.dtype)
     else:

@@ -37,7 +37,8 @@ programs also return, summed over layers and servers, the rows their
 dispatch buffers dropped and the grouped GEMM's row tiles, live and laid
 out; :meth:`Executor.pull_load` brings them to the host with the step's
 expert load, in one transfer, and adds them to ``moe.dropped_rows``,
-``gemm.tiles_live`` and ``gemm.tiles``.
+``gemm.tiles_live`` and ``gemm.tiles``, and the load's sum (the (token,
+k) routes that landed on the experts held) to ``moe.routes_held``.
 """
 
 from __future__ import annotations
@@ -244,7 +245,9 @@ class Executor:
         if counts is not None:
             for name, n in zip(COUNTERS, counts):
                 tracing.count(name, int(n))
-        return np.asarray(load)
+        load = np.asarray(load)
+        tracing.count("moe.routes_held", int(load.sum()))
+        return load
 
     # ------------------------------------------------------------ prefill
     def prefill(self, slot: int, prompt: np.ndarray) -> jax.Array:

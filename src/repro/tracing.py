@@ -5,9 +5,10 @@ spans and counters.  Always on, and bounded.
   ``jax.named_scope`` (XProf's framework-op view groups by it) and sets
   the XLA frontend attribute ``eaas_stage``, which the compiled program
   keeps on each instruction and the profiler shows in the op's text.  It
-  runs at trace time and adds nothing at run time.  Stages: ``attention``,
-  ``router``, ``dispatch``, ``server``, ``combine``, ``head``; the grouped
-  GEMM kernel carries ``gemm`` in its own ``kernel_metadata``.
+  runs at trace time and adds nothing at run time.  Stages: ``attention``
+  (GQA), ``mla`` (latent attention, in its place), ``router``,
+  ``dispatch``, ``server``, ``combine``, ``head``; the grouped GEMM kernel
+  carries ``gemm`` in its own ``kernel_metadata``.
 * :class:`span` marks host work: a ``jax.profiler.TraceAnnotation``, so it
   lies on the device trace's clock, and a record ``(name, start, end,
   args)`` on ``time.perf_counter`` in a process-wide ring of

@@ -2,8 +2,10 @@
 
 The TPU compiler is installed with jax and compiles for a described
 topology: each test lowers a Pallas kernel, or the served decode step, at
-the ``deepseek-r1-chip`` widths (the grouped GEMM at Mixtral-8x7B's too)
-and compiles it, which raises what the chip's compiler would raise
+the ``deepseek-r1-chip`` widths (latent attention, 16 of 256 experts; the
+grouped GEMM at Mixtral-8x7B's too, and the GQA decode kernels at its
+grouped-query widths) and compiles it, which raises what the chip's
+compiler would raise
 (unaligned blocks, refused kernel bodies, blocks that overflow VMEM,
 programs that do not fit); the compiled decode step must also keep the
 program's stage tags.  Nothing runs, so nothing here says anything
@@ -31,6 +33,7 @@ HBM_BYTES = 16 * 2 ** 30          # one v5e chip
 CFG = get_config("deepseek-r1-chip")
 BATCH, SEQ, CHUNK = 32, 2048, 64  # the smoke's engine shape
 MIXTRAL_D, MIXTRAL_F = 4096, 14336   # bench/configs/mixtral-8x7b.pp8.json
+MIXTRAL_HEADS, MIXTRAL_KV, MIXTRAL_HD = 32, 8, 128
 
 
 @pytest.fixture(scope="module")
@@ -85,9 +88,9 @@ def test_grouped_gemm_compiles(one_chip, servers, rows, groups, k, n):
 
 
 def test_flash_decode_compiles(one_chip):
-    kv = (BATCH, SEQ, CFG.num_kv_heads, CFG.head_dim)
+    kv = (BATCH, SEQ, MIXTRAL_KV, MIXTRAL_HD)
     _compile(lambda q, k, v, n: ops.flash_decode(q, k, v, n, impl="pallas"),
-             _spec(one_chip, (BATCH, CFG.num_heads, CFG.head_dim),
+             _spec(one_chip, (BATCH, MIXTRAL_HEADS, MIXTRAL_HD),
                    jnp.bfloat16),
              _spec(one_chip, kv, jnp.bfloat16),
              _spec(one_chip, kv, jnp.bfloat16),
@@ -97,10 +100,10 @@ def test_flash_decode_compiles(one_chip):
 def test_paged_flash_decode_compiles(one_chip):
     bs = 16                       # EngineConfig.kv_block_size
     per_seq = SEQ // bs
-    pool = (BATCH * per_seq + 1, bs, CFG.num_kv_heads, CFG.head_dim)
+    pool = (BATCH * per_seq + 1, bs, MIXTRAL_KV, MIXTRAL_HD)
     _compile(lambda q, k, v, t, n: ops.paged_flash_decode(
         q, k, v, t, n, impl="pallas"),
-        _spec(one_chip, (BATCH, CFG.num_heads, CFG.head_dim), jnp.bfloat16),
+        _spec(one_chip, (BATCH, MIXTRAL_HEADS, MIXTRAL_HD), jnp.bfloat16),
         _spec(one_chip, pool, jnp.bfloat16),
         _spec(one_chip, pool, jnp.bfloat16),
         _spec(one_chip, (BATCH, per_seq), jnp.int32),
@@ -149,6 +152,7 @@ def test_decode_step_fits_one_chip(decode_step):
     mem = decode_step.memory_analysis()
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"decode step peak {peak / 2 ** 30:.2f} GiB")
     assert peak < HBM_BYTES, peak / 2 ** 30
 
 
@@ -156,7 +160,26 @@ def test_decode_step_carries_stage_tags(decode_step):
     """Every stage tag reaches the chip's program: the XLA ops' frontend
     attribute, and the grouped GEMM kernel's own ``kernel_metadata``."""
     text = decode_step.as_text()
-    for stage in ("attention", "router", "dispatch", "server", "combine",
+    for stage in ("mla", "router", "dispatch", "server", "combine",
                   "head"):
         assert f'{tracing.STAGE_KEY}="{stage}"' in text, stage
     assert f'"{tracing.STAGE_KEY}":"gemm"' in text
+    # latent attention is tagged in the place of GQA's
+    assert f'{tracing.STAGE_KEY}="attention"' not in text
+
+
+def test_latent_attention_products_keep_the_tag(decode_step):
+    """Each fusion that computes one of latent attention's products carries
+    the ``mla`` tag on itself, where the profiler's op text shows it: a
+    fusion that ends in an untagged op (a layout bitcast, the residual add
+    of W_o) would leave that product's time out of ``mla_ms``."""
+    products = []
+    for comp in decode_step.as_text().split("\n\n"):
+        if "fus" in comp.strip().split("(", 1)[0]:
+            continue                      # the body of a fusion
+        products += [line for line in comp.splitlines()
+                     if "kind=kOutput" in line and "/mla/" in line]
+    assert products
+    untagged = [line.split(" = ")[0].strip() for line in products
+                if f'{tracing.STAGE_KEY}="mla"' not in line]
+    assert not untagged, untagged

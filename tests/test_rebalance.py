@@ -21,9 +21,15 @@ NUM_EXPERTS, NUM_SERVERS, MAX_BATCH = 16, 4, 8
 
 
 def _cfg(num_experts=NUM_EXPERTS):
+    # plain top-k routing: this module's Zipf trace and its pins were set
+    # on it; under deepseek-r1's group-limited routing the hot set leaves
+    # the second-choice experts a few hundredths of a token each, and the
+    # last placement is then decided by loads whose re-plan gains less
+    # than min_gain (tests/test_router_groups.py tests that routing)
     cfg = get_config("deepseek-r1").reduced()
     return cfg.replace(moe=dataclasses.replace(cfg.moe,
-                                               num_experts=num_experts))
+                                               num_experts=num_experts,
+                                               n_group=1, topk_group=1))
 
 
 def _engine(cfg, rebalance: bool) -> ServingEngine:
