@@ -14,7 +14,7 @@ This is the TPU adaptation of the paper's IBGDA one-sided RDMA library
   is a single psum of the (tiny) per-token outputs.  This is a beyond-paper
   optimization available only because of the disaggregated buffer layout.
 * ``mode="local"``      — single-device simulation (tests / CPU examples):
-  the identity transfer; servers are vmapped.
+  the identity transfer; the servers are served in one call.
 
 The asymmetry of the paper's protocol ("the server does not initiate any
 communication") is preserved structurally: transfers appear only in

@@ -13,7 +13,7 @@ failover and elastic scaling trivial.
 
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,12 +25,13 @@ from repro.models.common import dense_init
 
 
 class ServerWeights(NamedTuple):
-    """One server's expert weights: primaries + redundant (replica) slots.
+    """The expert weights of S servers: primaries + redundant (replica) slots.
 
-    Shapes (single server view):
-      w_gate/w_up: (L, d, f)   w_down: (L, f, d)
-    where L = E/S primaries + n_red redundant slots.
-    ``local_table``: (E,) int32 — global expert id -> local slot (or -1).
+    Shapes: w_gate/w_up: (S, L, d, f)   w_down: (S, L, f, d)
+    where L = E/S primaries + n_red redundant slots; a stacked layer axis
+    may lead them, (layers, S, L, ...), when :func:`serve` is given the
+    layer to read.
+    ``local_table``: (S, E) int32 — global expert id -> local slot (or -1).
     """
 
     w_gate: jax.Array
@@ -212,39 +213,56 @@ class ServeStats(NamedTuple):
 
 def serve(tokens: jax.Array, expert_ids: jax.Array, scores: jax.Array,
           counts: jax.Array, weights: ServerWeights, *,
-          impl: str = "auto") -> Tuple[jax.Array, ServeStats]:
-    """Process one aggregated dynamic batch on one server.
+          impl: str = "auto", layer: Optional[jax.Array] = None
+          ) -> Tuple[jax.Array, ServeStats]:
+    """Process the aggregated dynamic batches of S servers in one call.
 
-    tokens: (Clients, C, d) — the server's view of every client slot;
-    expert_ids/scores: (Clients, C); counts: (Clients,) header.
-    Returns (Clients, C, d) score-weighted outputs (zeros on invalid slots)
-    and ServeStats.
+    tokens: (S, Clients, C, d) — each server's view of every client slot;
+    expert_ids/scores: (S, Clients, C); counts: (S, Clients) headers.
+    Returns (S, Clients, C, d) score-weighted outputs (zeros on invalid
+    slots) and ServeStats summed over the servers.
+
+    The servers share one grouped GEMM per weight: a row's group is
+    ``server·L + slot``, so every server's experts are groups of one
+    (S·L, d, f) bank, a reshape of the stacked weights.  Given ``layer``,
+    the weights carry a leading layer axis and the GEMM reads that layer's
+    groups in place, from ``layer·S·L`` on: no weight is sliced or copied.
     """
-    Sc, C, d = tokens.shape
+    S, Sc, C, d = tokens.shape
     M = Sc * C
-    x = tokens.reshape(M, d)
-    eid = expert_ids.reshape(M)
-    sc = scores.reshape(M)
-    valid = (jnp.arange(C)[None, :] < counts[:, None]).reshape(M)
+    N = S * M
+    x = tokens.reshape(N, d)
+    eid = expert_ids.reshape(S, M)
+    sc = scores.reshape(N)
+    valid = (jnp.arange(C)[None, None, :] < counts[:, :, None]).reshape(S, M)
     valid &= eid >= 0
 
-    L = weights.w_gate.shape[0]
-    slot = jnp.where(valid, weights.local_table[jnp.clip(eid, 0)], L)
-    hosted = slot >= 0
-    miss = jnp.sum(valid & ~hosted)
-    slot = jnp.where(hosted, slot, L)                         # L = padding grp
+    L = weights.w_gate.shape[-3]
+    G = S * L                          # groups; group G is the pad group
+    # each row's slot, from its own server's table
+    slot = jnp.take_along_axis(weights.local_table, jnp.clip(eid, 0), axis=1)
+    group = (jnp.arange(S, dtype=slot.dtype)[:, None] * L + slot).reshape(N)
+    valid = valid.reshape(N)
+    hosted = slot.reshape(N) >= 0
+    live = valid & hosted
+    group = jnp.where(live, group, G)
 
-    # ---- reorganize tokens by local expert (paper Fig. 5) --------------
-    order = jnp.argsort(slot)                                 # stable
+    # ---- reorganize tokens by (server, local expert) (paper Fig. 5) ----
+    order = jnp.argsort(group)                                # stable
     xs = x[order]
-    group_sizes = jnp.bincount(slot, length=L + 1)[:L]        # drop pad group
+    group_sizes = jnp.bincount(group, length=G + 1)[:G]       # drop pad group
 
     # ---- grouped GEMM over active groups only (group-shrink) -----------
     # per-expert capacity for the dense lowering: ideal share × the buffer
     # capacity factor (under-provisioned experts drop, exactly like slots)
-    ecap = max(8, ((-(-(M * 5) // (4 * L))) + 7) // 8 * 8)
-    gg = lambda a, w: kops.grouped_gemm_counted(a, w, group_sizes, impl=impl,
-                                                expert_capacity=ecap)
+    ecap = max(8, ((-(-(N * 5) // (4 * G))) + 7) // 8 * 8)
+    offset = 0 if layer is None else layer * G
+
+    def gg(a, w):
+        return kops.grouped_gemm_counted(
+            a, w.reshape(-1, *w.shape[-2:]), group_sizes, impl=impl,
+            expert_capacity=ecap, group_offset=offset)
+
     h_gate, t_gate = gg(xs, weights.w_gate)
     h_up, t_up = gg(xs, weights.w_up)
     h = jax.nn.silu(h_gate.astype(jnp.float32)).astype(h_up.dtype) * h_up
@@ -252,11 +270,10 @@ def serve(tokens: jax.Array, expert_ids: jax.Array, scores: jax.Array,
 
     # ---- score weighting + masking, back to slot order ------------------
     y = y.astype(jnp.float32) * sc[order][:, None]
-    in_group = jnp.arange(M) < jnp.sum(group_sizes)           # pad rows off
-    y = jnp.where((valid[order] & hosted[order] & in_group)[:, None], y, 0)
-    out = jnp.zeros((M, d), jnp.float32).at[order].set(y)
-    out = out.reshape(Sc, C, d).astype(tokens.dtype)
+    y = jnp.where(live[order][:, None], y, 0)                 # pad rows off
+    out = jnp.zeros((N, d), jnp.float32).at[order].set(y)
+    out = out.reshape(S, Sc, C, d).astype(tokens.dtype)
     return out, ServeStats(
-        miss=miss, served=jnp.sum(valid & hosted),
+        miss=jnp.sum(valid & ~hosted), served=jnp.sum(live),
         tiles_live=t_gate.live + t_up.live + t_down.live,
         tiles=t_gate.total + t_up.total + t_down.total)

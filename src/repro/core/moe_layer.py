@@ -2,8 +2,11 @@
 
 One function, three execution modes (DESIGN.md §2):
 
-* ``axis_name=None``  — single-device simulation: the S logical servers are
-  vmapped.  Used by CPU tests, the host-level serving engine and examples.
+* ``axis_name=None``  — one device: the S logical servers are served in one
+  call, their experts the groups of one grouped GEMM per weight
+  (:func:`repro.core.expert_server.serve`).  Used by the serving engine on
+  the chip, CPU tests and examples.  Given ``layer``, the server weights
+  are every layer's, stacked, and the GEMM reads that layer's in place.
 * ``mode="a2a"``      — SPMD inside shard_map: tokens sharded over the server
   axis; one all_to_all each way (train / prefill).
 * ``mode="replicated"`` — SPMD decode: activations replicated over the server
@@ -159,6 +162,7 @@ def eaas_moe_apply(params: Dict, x: jax.Array, cfg_moe: MoEConfig,
                    runtime: MoERuntime, *, activation: str = "swiglu",
                    axis_name: Optional[str] = None, mode: str = "local",
                    token_salt: Optional[jax.Array] = None,
+                   layer: Optional[jax.Array] = None,
                    ) -> Tuple[jax.Array, MoEStats]:
     """Apply the EAAS MoE layer to x: (T, d) -> (T, d).
 
@@ -170,7 +174,12 @@ def eaas_moe_apply(params: Dict, x: jax.Array, cfg_moe: MoEConfig,
 
     In SPMD modes this must be called inside shard_map with ``axis_name``
     bound to the server mesh axis; params["servers"] arrays then hold only
-    the local shard (leading dim 1) and are squeezed here.
+    the local shard (leading dim 1), one server of the same call.
+
+    On one device, ``layer`` (a scalar, traced under a layer scan) says
+    that params["servers"] arrays are the stacked bank of every layer,
+    (layers, S, L, ...), and which layer this is: the grouped GEMM reads
+    that layer's experts straight out of the bank.
 
     Device work is tagged by stage (:func:`repro.tracing.stage`):
     ``router``, ``dispatch`` (replica lookup, packing, the send),
@@ -206,23 +215,15 @@ def eaas_moe_apply(params: Dict, x: jax.Array, cfg_moe: MoEConfig,
     # ---- server compute ----------------------------------------------------
     sw = params["servers"]
     with tracing.stage("server"):
-        if axis_name is None:
-            # vmap the stateless server over the S logical instances
-            def one_server(wg, wu, wd, tbl, hid, eid, sc, cnt):
-                w = ServerWeights(wg, wu, wd, tbl)
-                out, st = expert_server.serve(hid[None], eid[None], sc[None],
-                                              cnt[None], w,
-                                              impl=runtime.gemm_impl)
-                return out[0], st
-            out_slots, st = jax.vmap(one_server)(
-                sw["w_gate"], sw["w_up"], sw["w_down"], runtime.local_table,
-                hid, eid, sc, cnt)
-            st = jax.tree.map(jnp.sum, st)
-        else:
-            w = ServerWeights(sw["w_gate"][0], sw["w_up"][0],
-                              sw["w_down"][0], runtime.local_table[0])
-            out_slots, st = expert_server.serve(hid, eid, sc, cnt, w,
-                                                impl=runtime.gemm_impl)
+        w = ServerWeights(sw["w_gate"], sw["w_up"], sw["w_down"],
+                          runtime.local_table)
+        if axis_name is None:     # slot dim0 is the server; one client
+            slots = [a[:, None] for a in (hid, eid, sc, cnt)]
+        else:                     # this shard's one server; every client
+            slots = [a[None] for a in (hid, eid, sc, cnt)]
+        out, st = expert_server.serve(*slots, w, impl=runtime.gemm_impl,
+                                      layer=layer)
+        out_slots = out.reshape(hid.shape)
 
     # ---- client: combine (weighted sum arrives pre-weighted) -------------
     with tracing.stage("combine"):

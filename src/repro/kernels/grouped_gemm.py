@@ -12,10 +12,13 @@ to hide the step's fixed cost (~0.3 µs on v5e, against 0.04 µs for a
 128×128 bf16 block).  Unless the caller gives them, :func:`choose_blocks`
 takes tk and tn from K and N: the largest blocks, power-of-two multiples of
 128 dividing the dimension, whose double-buffered working set (x block and
-weight block, plus the f32 output block) fits :data:`VMEM_BUDGET`; at
-Mixtral's 4096×14336 that is a 2048×1024 weight block of 4 MiB (larger
-blocks gained under 1% on v5e).  The scoped VMEM limit is raised to the
-working set when it passes the default.
+weight block, plus the f32 output block) fits :data:`VMEM_BUDGET` (larger
+blocks gained under 1% on v5e); of those, the one that reads each row
+tile's x rows the fewest times, since XLA may leave x in HBM, where every
+read competes with the weights.  At Mixtral's 4096×14336 that is a
+4096×512 weight block of 4 MiB, whose x block stays put over the tile's N
+blocks.  The scoped VMEM limit is raised to the working set when it passes
+the default.
 
 Dead row tiles fetch nothing.  The pipeline skips a block's copy when its
 index equals the previous grid step's, so a dead tile's weight block is
@@ -65,18 +68,27 @@ def _block_sizes(dim: int) -> Tuple[int, ...]:
     return sizes or (dim,)
 
 
+def x_reads(k: int, n: int, tk: int, tn: int) -> int:
+    """How often a live row tile reads its x rows: once for each N block,
+    since the K blocks run innermost, unless one K block spans K and the
+    x block stays put."""
+    return 1 if tk == k else n // tn
+
+
 def choose_blocks(k: int, n: int, *, tm: int = 128, x_bytes: int = 2,
                   w_bytes: int = 2, budget: int = VMEM_BUDGET
                   ) -> Tuple[int, int]:
-    """(tk, tn): the largest weight block whose working set fits ``budget``,
-    and of those the one with the smallest working set; the smallest blocks
-    when none fits."""
+    """(tk, tn): the largest weight block whose working set fits ``budget``;
+    of those, the one that reads each row tile's x the fewest times
+    (:func:`x_reads`), then the one with the smallest working set; the
+    smallest blocks when none fits."""
     best, score = (_block_sizes(k)[0], _block_sizes(n)[0]), None
     for tk in _block_sizes(k):
         for tn in _block_sizes(n):
             ws = working_set(tm, tk, tn, x_bytes, w_bytes)
-            if ws <= budget and (score is None or (tk * tn, -ws) > score):
-                best, score = (tk, tn), (tk * tn, -ws)
+            key = (tk * tn, -x_reads(k, n, tk, tn), -ws)
+            if ws <= budget and (score is None or key > score):
+                best, score = (tk, tn), key
     return best
 
 
@@ -96,19 +108,23 @@ def _kernel(tile_gid, tile_valid, x_ref, w_ref, o_ref):
 
 def grouped_gemm_pallas(x_sorted: jax.Array, w: jax.Array,
                         group_sizes: jax.Array, *,
+                        group_offset=0,
                         tm: int = 128, tn: Optional[int] = None,
                         tk: Optional[int] = None,
                         interpret: bool = False
                         ) -> Tuple[jax.Array, RowTiles]:
-    """x_sorted: (M, K) rows sorted by group; w: (G, K, N); -> ((M, N),
-    the row tiles).
+    """x_sorted: (M, K) rows sorted by group; w: (W, K, N); group_sizes:
+    (G,); -> ((M, N), the row tiles).
 
-    Rows beyond ``sum(group_sizes)`` yield zeros.  K and N must be multiples
-    of tk/tn; blocks not given are chosen from the shapes
-    (:func:`choose_blocks`).
+    Group ``g`` multiplies by ``w[group_offset + g]``: the offset (a
+    scalar, traced or not) goes into the tile table the kernel prefetches,
+    so a bank holding more groups than the call (a stacked layer axis
+    folded into W) is read in place, never sliced.  Rows beyond
+    ``sum(group_sizes)`` yield zeros.  K and N must be multiples of tk/tn;
+    blocks not given are chosen from the shapes (:func:`choose_blocks`).
     """
     M, K = x_sorted.shape
-    G, K2, N = w.shape
+    _, K2, N = w.shape
     assert K == K2, (K, K2)
     auto_tk, auto_tn = choose_blocks(
         K, N, tm=tm, x_bytes=x_sorted.dtype.itemsize,
@@ -154,7 +170,7 @@ def grouped_gemm_pallas(x_sorted: jax.Array, w: jax.Array,
         interpret=interpret,
         name="eaas_grouped_gemm",
         metadata={tracing.STAGE_KEY: "gemm"},
-    )(table.tile_gid, table.tile_valid, x_pad, w)
+    )(table.tile_gid + group_offset, table.tile_valid, x_pad, w)
 
     out = gs.unpad_rows(out_pad, padded_idx, row_live)
     tiles = RowTiles(live=table.num_tiles, total=jnp.int32(T))

@@ -8,15 +8,21 @@ Every op has several implementations:
 
 The grouped GEMM has two more:
 
-* ``xla_ragged`` — ``jax.lax.ragged_dot``: exact, executes fast on CPU; its
-  HLO flop count on CPU over-counts by G× (XLA decomposes into masked dots),
-  so it is NOT used for the roofline dry-run.  The TPU compiler refuses a
-  *batched* ragged dot, which is what ``vmap`` over the logical expert
-  servers makes of it.
+* ``xla_ragged`` — :func:`grouped_gemm_masked`, a batched matmul over the
+  groups on masked rows: exact, and a row's result is the same wherever its
+  group sits in the bank, so moving an expert to another server or slot
+  never changes its outputs.  Its flop count over-counts by G×, so it is
+  NOT used for the roofline dry-run.
 * ``xla_dense`` — per-expert-capacity batched matmul (GShard-style): the
   flop-honest XLA lowering used by the dry-run; FLOPs = 2·L·cap·d·f which at
   the configured capacity factor equals the ideal grouped-GEMM work.  Rows
   past an expert's capacity are dropped, so it is not exact under skew.
+
+A grouped GEMM may be given a ``group_offset``: group ``g`` then multiplies
+by ``w[group_offset + g]``, so one call reads its groups out of a larger
+bank (every layer's expert servers stacked).  The Pallas kernel adds the
+offset to its tile table and reads the bank in place; the XLA lowerings
+slice the call's groups out with ``lax.dynamic_slice_in_dim``.
 
 ``impl="auto"`` picks from the platform: ``pallas`` on TPU; on the CPU
 ``xla_ragged`` for the grouped GEMM and ``ref`` (the plain XLA lowering)
@@ -95,28 +101,45 @@ def grouped_gemm_dense(x_sorted: jax.Array, w: jax.Array,
     return out.astype(x_sorted.dtype)
 
 
+def grouped_gemm_masked(x_sorted: jax.Array, w: jax.Array,
+                        group_sizes: jax.Array) -> jax.Array:
+    """Exact grouped matmul: one batched matmul over the groups, each on
+    the rows of its own group (the others masked to zero), summed over the
+    groups.  A row meets only its group's K products, so its result does
+    not depend on where its group sits in the bank or how many groups
+    there are; ``jax.lax.ragged_dot`` on the CPU contracts over all G·K
+    products at once, and its sums then move in the last bits when the
+    groups do.  Rows past ``sum(group_sizes)`` yield zeros."""
+    G = w.shape[0]
+    gid = jnp.searchsorted(jnp.cumsum(group_sizes),
+                           jnp.arange(x_sorted.shape[0]), side="right")
+    mine = gid[None, :, None] == jnp.arange(G)[:, None, None]
+    y = jnp.einsum("gmk,gkn->gmn", jnp.where(mine, x_sorted[None], 0), w,
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(y, axis=0).astype(x_sorted.dtype)
+
+
 def grouped_gemm(x_sorted: jax.Array, w: jax.Array, group_sizes: jax.Array,
                  *, impl: str = "auto", expert_capacity: Optional[int] = None,
-                 tm: int = 128, tn: Optional[int] = None,
+                 group_offset=0, tm: int = 128, tn: Optional[int] = None,
                  tk: Optional[int] = None) -> jax.Array:
-    """out[i] = x_sorted[i] @ w[g(i)] — see module docstring for impls.
-    The XLA lowerings are traced in the ``gemm`` stage; the Pallas kernel
-    carries that tag in its own metadata, and takes the blocks not given
-    from the shapes (:func:`repro.kernels.grouped_gemm.choose_blocks`)."""
+    """out[i] = x_sorted[i] @ w[group_offset + g(i)] — see module docstring
+    for impls.  The XLA lowerings are traced in the ``gemm`` stage; the
+    Pallas kernel carries that tag in its own metadata, and takes the
+    blocks not given from the shapes
+    (:func:`repro.kernels.grouped_gemm.choose_blocks`)."""
     impl = _resolve("grouped_gemm", impl, "xla_ragged", _GEMM_IMPLS)
     if impl in ("pallas", "pallas_interpret"):
         return grouped_gemm_pallas(
-            x_sorted, w, group_sizes, tm=tm, tn=tn, tk=tk,
-            interpret=(impl == "pallas_interpret"))[0]
+            x_sorted, w, group_sizes, group_offset=group_offset, tm=tm,
+            tn=tn, tk=tk, interpret=(impl == "pallas_interpret"))[0]
     with tracing.stage("gemm"):
+        w = jax.lax.dynamic_slice_in_dim(w, group_offset,
+                                         group_sizes.shape[0])
         if impl == "ref":
             return kref.grouped_gemm_ref(x_sorted, w, group_sizes)
         if impl == "xla_ragged":
-            y = jax.lax.ragged_dot(x_sorted, w,
-                                   group_sizes.astype(jnp.int32))
-            # ragged_dot leaves rows past sum(group_sizes) unspecified
-            live = jnp.arange(x_sorted.shape[0]) < jnp.sum(group_sizes)
-            return jnp.where(live[:, None], y, 0).astype(x_sorted.dtype)
+            return grouped_gemm_masked(x_sorted, w, group_sizes)
         M, G = x_sorted.shape[0], w.shape[0]
         cap = expert_capacity or max(_ceil_mult(2 * M // max(G, 1) + 1, 8), 8)
         return grouped_gemm_dense(x_sorted, w, group_sizes, cap)
@@ -124,17 +147,19 @@ def grouped_gemm(x_sorted: jax.Array, w: jax.Array, group_sizes: jax.Array,
 
 def grouped_gemm_counted(x_sorted: jax.Array, w: jax.Array,
                          group_sizes: jax.Array, *, impl: str = "auto",
-                         expert_capacity: Optional[int] = None
-                         ) -> Tuple[jax.Array, RowTiles]:
+                         expert_capacity: Optional[int] = None,
+                         group_offset=0) -> Tuple[jax.Array, RowTiles]:
     """:func:`grouped_gemm` and the Pallas kernel's row tiles, live and
     laid out; the XLA lowerings have none and count zero."""
     impl = _resolve("grouped_gemm", impl, "xla_ragged", _GEMM_IMPLS)
     if impl in ("pallas", "pallas_interpret"):
         return grouped_gemm_pallas(x_sorted, w, group_sizes,
+                                   group_offset=group_offset,
                                    interpret=(impl == "pallas_interpret"))
     zero = jnp.zeros((), jnp.int32)
     return (grouped_gemm(x_sorted, w, group_sizes, impl=impl,
-                         expert_capacity=expert_capacity),
+                         expert_capacity=expert_capacity,
+                         group_offset=group_offset),
             RowTiles(zero, zero))
 
 

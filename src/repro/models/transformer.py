@@ -86,7 +86,10 @@ def _P(*args):
 # ---------------------------------------------------------------------------
 
 def _moe_apply(params_moe: Dict, x2d: jax.Array, cfg: ModelConfig,
-               ctx: ParallelCtx) -> Tuple[jax.Array, MoEStats]:
+               ctx: ParallelCtx, layer: Optional[jax.Array] = None
+               ) -> Tuple[jax.Array, MoEStats]:
+    """The MoE layer; on one device, ``layer`` (from :func:`_layer_scan`)
+    says that ``params_moe["servers"]`` is every layer's bank, stacked."""
     m = cfg.moe
     rt = ctx.moe_runtime
     assert rt is not None, "MoE arch needs ctx.moe_runtime"
@@ -94,7 +97,7 @@ def _moe_apply(params_moe: Dict, x2d: jax.Array, cfg: ModelConfig,
     if ctx.mesh is None:
         return eaas.eaas_moe_apply(params_moe, x2d, m, rt,
                                    activation=cfg.activation,
-                                   axis_name=None, mode="local")
+                                   axis_name=None, mode="local", layer=layer)
 
     mode = ctx.moe_mode
     dp = ctx.dp_axes
@@ -220,7 +223,7 @@ def _block_train(p: Dict, cfg: ModelConfig, x: jax.Array,
 
 def _block_decode(p: Dict, cfg: ModelConfig, x: jax.Array,
                   cache: kvc.KVCache, ctx: ParallelCtx, *,
-                  is_local: bool = False, mrope_positions=None
+                  is_local: bool = False, mrope_positions=None, layer=None
                   ) -> Tuple[jax.Array, kvc.KVCache, Optional[MoEStats]]:
     B, _, d = x.shape
     h = rms_norm(x, p["ln1"], cfg.rms_norm_eps)
@@ -235,7 +238,7 @@ def _block_decode(p: Dict, cfg: ModelConfig, x: jax.Array,
     h = rms_norm(x, p["ln2"], cfg.rms_norm_eps)
     stats = None
     if "moe" in p:
-        y, stats = _moe_apply(p["moe"], h.reshape(B, d), cfg, ctx)
+        y, stats = _moe_apply(p["moe"], h.reshape(B, d), cfg, ctx, layer)
         h = y.reshape(B, 1, d)
     else:
         h = mlp(p["mlp"], h, cfg.activation)
@@ -353,35 +356,57 @@ def _scan_train(blocks: Dict, cfg: ModelConfig, x: jax.Array,
     return x, stats
 
 
-def _scan_prefill(blocks: Dict, caches, cfg: ModelConfig, x: jax.Array,
-                  positions: jax.Array, ctx: ParallelCtx, *,
-                  is_local: bool = False, mrope=None):
+def _layer_scan(block, blocks: Dict, caches, cfg: ModelConfig,
+                x: jax.Array, ctx: ParallelCtx):
+    """Scan ``block(p, x, cache, layer)`` over a served stack's layers and
+    their caches; returns (x, caches, per-layer stats).
+
+    On one device the expert bank stays out of the scan's xs: each layer's
+    MoE gets the whole stacked bank and its layer index, and the grouped
+    GEMM reads the layer's experts in place.  A scanned slice of the bank
+    would be copied, since it feeds the kernel's custom call.  The SPMD
+    island takes its shard of the layer's slice as before.
+    """
+    bank = None
+    if ctx.mesh is None and "moe" in blocks:
+        moe = dict(blocks["moe"])
+        bank = moe.pop("servers")
+        blocks = dict(blocks, moe=moe)
+
     def body(xc, inp):
-        p, c = inp
-        out, nc, stats = _block_prefill(p, cfg, xc, positions, c, ctx,
-                                        is_local=is_local,
-                                        mrope_positions=mrope)
+        p, c, layer = inp
+        if bank is None:
+            layer = None
+        else:
+            p = dict(p, moe=dict(p["moe"], servers=bank))
+        out, nc, stats = block(p, xc, c, layer)
         if stats is None:
             stats = _zero_stats(cfg)
         return out, (nc, stats)
-    x, (ncaches, stats) = jax.lax.scan(body, x, (blocks, caches),
-                                       unroll=ctx.unroll_scans)
+    n = jax.tree.leaves(blocks)[0].shape[0]
+    x, (ncaches, stats) = jax.lax.scan(
+        body, x, (blocks, caches, jnp.arange(n, dtype=jnp.int32)),
+        unroll=ctx.unroll_scans)
     return x, ncaches, stats
+
+
+def _scan_prefill(blocks: Dict, caches, cfg: ModelConfig, x: jax.Array,
+                  positions: jax.Array, ctx: ParallelCtx, *,
+                  is_local: bool = False, mrope=None):
+    return _layer_scan(
+        lambda p, xc, c, layer: _block_prefill(
+            p, cfg, xc, positions, c, ctx, is_local=is_local,
+            mrope_positions=mrope, layer=layer),
+        blocks, caches, cfg, x, ctx)
 
 
 def _scan_decode(blocks: Dict, caches, cfg: ModelConfig, x: jax.Array,
                  ctx: ParallelCtx, *, is_local: bool = False, mrope=None):
-    def body(xc, inp):
-        p, c = inp
-        out, nc, stats = _block_decode(p, cfg, xc, c, ctx,
-                                       is_local=is_local,
-                                       mrope_positions=mrope)
-        if stats is None:
-            stats = _zero_stats(cfg)
-        return out, (nc, stats)
-    x, (ncaches, stats) = jax.lax.scan(body, x, (blocks, caches),
-                                       unroll=ctx.unroll_scans)
-    return x, ncaches, stats
+    return _layer_scan(
+        lambda p, xc, c, layer: _block_decode(
+            p, cfg, xc, c, ctx, is_local=is_local, mrope_positions=mrope,
+            layer=layer),
+        blocks, caches, cfg, x, ctx)
 
 
 def _zero_stats(cfg: ModelConfig) -> MoEStats:
@@ -418,7 +443,7 @@ def _stack_one(st: Optional[MoEStats], cfg: ModelConfig) -> MoEStats:
 def _block_prefill(p: Dict, cfg: ModelConfig, x: jax.Array,
                    positions: jax.Array, cache: kvc.KVCache,
                    ctx: ParallelCtx, *, is_local: bool = False,
-                   mrope_positions=None):
+                   mrope_positions=None, layer=None):
     B, S, d = x.shape
     h = rms_norm(x, p["ln1"], cfg.rms_norm_eps)
     h, (k, v) = attn.full_attention(
@@ -430,7 +455,8 @@ def _block_prefill(p: Dict, cfg: ModelConfig, x: jax.Array,
     h = rms_norm(x, p["ln2"], cfg.rms_norm_eps)
     stats = None
     if "moe" in p:
-        y, stats = _moe_apply(p["moe"], h.reshape(B * S, d), cfg, ctx)
+        y, stats = _moe_apply(p["moe"], h.reshape(B * S, d), cfg, ctx,
+                              layer)
         h = y.reshape(B, S, d)
     else:
         h = mlp(p["mlp"], h, cfg.activation)
@@ -440,7 +466,8 @@ def _block_prefill(p: Dict, cfg: ModelConfig, x: jax.Array,
 
 def _block_prefill_chunk(p: Dict, cfg: ModelConfig, x: jax.Array,
                          positions: jax.Array, cache: kvc.KVCache,
-                         ctx: ParallelCtx, *, mrope_positions=None):
+                         ctx: ParallelCtx, *, mrope_positions=None,
+                         layer=None):
     """One block over a prompt *chunk*: attention against the cache (which
     holds every earlier chunk), chunk K/V written in.  Same math as
     :func:`_block_prefill` restricted to the chunk's rows."""
@@ -452,7 +479,8 @@ def _block_prefill_chunk(p: Dict, cfg: ModelConfig, x: jax.Array,
     h = rms_norm(x, p["ln2"], cfg.rms_norm_eps)
     stats = None
     if "moe" in p:
-        y, stats = _moe_apply(p["moe"], h.reshape(B * S, d), cfg, ctx)
+        y, stats = _moe_apply(p["moe"], h.reshape(B * S, d), cfg, ctx,
+                              layer)
         h = y.reshape(B, S, d)
     else:
         h = mlp(p["mlp"], h, cfg.activation)
@@ -463,16 +491,11 @@ def _block_prefill_chunk(p: Dict, cfg: ModelConfig, x: jax.Array,
 def _scan_prefill_chunk(blocks: Dict, caches, cfg: ModelConfig, x: jax.Array,
                         positions: jax.Array, ctx: ParallelCtx, *,
                         mrope=None):
-    def body(xc, inp):
-        p, c = inp
-        out, nc, stats = _block_prefill_chunk(p, cfg, xc, positions, c, ctx,
-                                              mrope_positions=mrope)
-        if stats is None:
-            stats = _zero_stats(cfg)
-        return out, (nc, stats)
-    x, (ncaches, stats) = jax.lax.scan(body, x, (blocks, caches),
-                                       unroll=ctx.unroll_scans)
-    return x, ncaches, stats
+    return _layer_scan(
+        lambda p, xc, c, layer: _block_prefill_chunk(
+            p, cfg, xc, positions, c, ctx, mrope_positions=mrope,
+            layer=layer),
+        blocks, caches, cfg, x, ctx)
 
 
 # ---------------------------------------------------------------------------

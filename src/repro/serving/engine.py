@@ -94,7 +94,7 @@ class EngineConfig:
     restart_steps: int = 50            # monolithic group restart cost
     tp_restart_steps: int = 12         # one TP unit restart
     tp_batch_cap: Optional[int] = None # TP: weight replication caps batch
-    # "auto": the Pallas grouped GEMM on TPU, ragged_dot on the CPU
+    # "auto": the Pallas grouped GEMM on TPU, xla_ragged on the CPU
     gemm_impl: str = "auto"
     eos_token: Optional[int] = None
     # --- scheduler knobs -------------------------------------------------

@@ -17,6 +17,7 @@ collects the same tests and only the one given this file loads it.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,25 +67,24 @@ def _compile(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("servers,rows,groups,k,n", [
+@pytest.mark.parametrize("rows,groups,bank,k,n", [
     # the chip cut: 4 primaries + 2 redundant slots on one server
-    (None, 256, 6, CFG.d_model, CFG.moe.d_expert),
-    (None, 256, 6, CFG.moe.d_expert, CFG.d_model),
-    # Mixtral-8x7B's stage: 4 servers vmapped, 2 experts and 264 rows each
-    (4, 264, 2, MIXTRAL_D, MIXTRAL_F),
-    (4, 264, 2, MIXTRAL_F, MIXTRAL_D),
+    (256, 6, 6, CFG.d_model, CFG.moe.d_expert),
+    (256, 6, 6, CFG.moe.d_expert, CFG.d_model),
+    # Mixtral-8x7B's stage: 4 servers of 2 experts and 264 rows each in one
+    # call, 8 groups read at an offset in the bank of all 4 layers
+    (4 * 264, 8, 4 * 8, MIXTRAL_D, MIXTRAL_F),
+    (4 * 264, 8, 4 * 8, MIXTRAL_F, MIXTRAL_D),
 ], ids=["gate_up", "down", "mixtral_gate_up", "mixtral_down"])
-def test_grouped_gemm_compiles(one_chip, servers, rows, groups, k, n):
+def test_grouped_gemm_compiles(one_chip, rows, groups, bank, k, n):
     """The blocks chosen from these shapes compile and fit the chip's
     VMEM."""
-    gemm = lambda x, w, g: ops.grouped_gemm(x, w, g, impl="pallas")
-    lead = ()
-    if servers:
-        gemm, lead = jax.vmap(gemm), (servers,)
-    _compile(gemm,
-             _spec(one_chip, (*lead, rows, k), jnp.bfloat16),
-             _spec(one_chip, (*lead, groups, k, n), jnp.bfloat16),
-             _spec(one_chip, (*lead, groups), jnp.int32))
+    _compile(lambda x, w, g, at: ops.grouped_gemm(
+                 x, w, g, impl="pallas", group_offset=at),
+             _spec(one_chip, (rows, k), jnp.bfloat16),
+             _spec(one_chip, (bank, k, n), jnp.bfloat16),
+             _spec(one_chip, (groups,), jnp.int32),
+             _spec(one_chip, (), jnp.int32))
 
 
 def test_flash_decode_compiles(one_chip):
@@ -166,6 +166,36 @@ def test_decode_step_carries_stage_tags(decode_step):
     assert f'"{tracing.STAGE_KEY}":"gemm"' in text
     # latent attention is tagged in the place of GQA's
     assert f'{tracing.STAGE_KEY}="attention"' not in text
+
+
+def test_decode_step_reads_expert_weights_in_place(decode_step):
+    """No op of the compiled decode step makes a buffer shaped like an
+    expert weight: the grouped GEMM reads the stacked bank of every layer
+    and server in place, so neither the layer scan nor the servers slice
+    or copy one.  Parameters, the layer loop's tuple elements and bitcasts
+    are views, and the ops inside a fusion make no buffer of their own."""
+    d, f = CFG.d_model, CFG.moe.d_expert
+    # the fixture's 4 MoE layers of 4 servers, 4 primaries + 2 redundant
+    groups = (CFG.num_layers - CFG.moe.first_k_dense) * 4 * (4 + 2)
+    op = re.compile(r"\s*(?:ROOT )?%(\S+) = bf16\[([\d,]*)\]\S* ([\w-]+)\(")
+    views = ("parameter", "get-tuple-element", "bitcast")
+    copies, banks = [], 0
+    for comp in decode_step.as_text().split("\n\n"):
+        if "fus" in comp.strip().split("(", 1)[0]:
+            continue                      # the body of a fusion
+        for line in comp.splitlines():
+            m = op.match(line)
+            if not m:
+                continue
+            dims = tuple(int(v) for v in m.group(2).split(",") if v)
+            if dims[-2:] not in ((d, f), (f, d)):
+                continue
+            if m.group(3) not in views:
+                copies.append(m.group(1))
+            elif m.group(3) == "bitcast" and dims[0] == groups:
+                banks += 1                # every layer's servers, as groups
+    assert not copies, copies
+    assert banks == 3                     # the kernel's gate, up and down
 
 
 def test_latent_attention_products_keep_the_tag(decode_step):

@@ -115,19 +115,19 @@ def test_serve_counts_miss_for_unhosted_expert():
     # this server hosts experts {0, 3} in slots {0, 1}
     local = jnp.asarray(np.array([0, -1, -1, 1], np.int32))
     w = ServerWeights(
-        w_gate=jnp.asarray(rng.normal(size=(L, d, f)).astype(np.float32)),
-        w_up=jnp.asarray(rng.normal(size=(L, d, f)).astype(np.float32)),
-        w_down=jnp.asarray(rng.normal(size=(L, f, d)).astype(np.float32)),
-        local_table=local)
-    tokens = jnp.asarray(rng.normal(size=(1, C, d)).astype(np.float32))
-    eids = jnp.asarray(np.array([[0, 2, 3, 0]], np.int32))   # expert 2 unhosted
-    scores = jnp.ones((1, C), jnp.float32)
-    counts = jnp.asarray(np.array([3], np.int32))            # last slot invalid
+        w_gate=jnp.asarray(rng.normal(size=(1, L, d, f)).astype(np.float32)),
+        w_up=jnp.asarray(rng.normal(size=(1, L, d, f)).astype(np.float32)),
+        w_down=jnp.asarray(rng.normal(size=(1, L, f, d)).astype(np.float32)),
+        local_table=local[None])
+    tokens = jnp.asarray(rng.normal(size=(1, 1, C, d)).astype(np.float32))
+    eids = jnp.asarray(np.array([[[0, 2, 3, 0]]], np.int32))  # 2 unhosted
+    scores = jnp.ones((1, 1, C), jnp.float32)
+    counts = jnp.asarray(np.array([[3]], np.int32))          # last invalid
 
     out, stats = serve(tokens, eids, scores, counts, w, impl="xla_ragged")
     assert int(stats.miss) == 1                     # the expert-2 token
     assert int(stats.served) == 2                   # experts 0 and 3
-    out = np.asarray(out)
+    out = np.asarray(out)[0]
     assert np.any(out[0, 0] != 0) and np.any(out[0, 2] != 0)
     np.testing.assert_array_equal(out[0, 1], 0)     # miss row zeroed
     np.testing.assert_array_equal(out[0, 3], 0)     # invalid row zeroed
@@ -140,12 +140,12 @@ def test_serve_all_hosted_no_miss():
             for k in ("w_gate", "w_up", "w_down")}
     sw = build_server_weights(bank, S, np.zeros((S, 0), np.int32))
     local = make_local_table(E, S, np.zeros((S, 0), np.int32))
-    w = ServerWeights(sw["w_gate"][0], sw["w_up"][0], sw["w_down"][0],
-                      jnp.asarray(local[0]))
-    tokens = jnp.asarray(rng.normal(size=(1, 2, d)).astype(np.float32))
-    eids = jnp.asarray(np.array([[0, 1]], np.int32))
-    _, stats = serve(tokens, eids, jnp.ones((1, 2)), jnp.asarray([2]), w,
-                     impl="xla_ragged")
+    w = ServerWeights(sw["w_gate"][:1], sw["w_up"][:1], sw["w_down"][:1],
+                      jnp.asarray(local[:1]))
+    tokens = jnp.asarray(rng.normal(size=(1, 1, 2, d)).astype(np.float32))
+    eids = jnp.asarray(np.array([[[0, 1]]], np.int32))
+    _, stats = serve(tokens, eids, jnp.ones((1, 1, 2)), jnp.asarray([[2]]),
+                     w, impl="xla_ragged")
     assert int(stats.miss) == 0 and int(stats.served) == 2
 
 

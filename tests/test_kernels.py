@@ -1,5 +1,6 @@
 """Per-kernel allclose sweeps vs the pure-jnp oracles (interpret mode)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -51,6 +52,31 @@ def test_grouped_gemm_xla_impls(impl, rng):
     np.testing.assert_allclose(out, exp, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("impl", ["pallas_interpret", "xla_ragged",
+                                  "xla_dense", "ref"])
+def test_group_offset_reads_the_bank_in_place(impl, rng):
+    """Groups read at an offset into a bank of three layers equal the same
+    call on that layer's slice, bit for bit; under the exact XLA lowering a
+    row's result also stays put when its group moves within the bank."""
+    m, k, n, g = 96, 32, 24, 5
+    sizes = jnp.asarray(np.array([10, 0, 40, 30, 16], np.int32))
+    x = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+    bank = jnp.asarray(rng.normal(size=(3 * g, k, n)) * 0.1, jnp.float32)
+    at = jax.jit(lambda x, w, s, o: ops.grouped_gemm(
+        x, w, s, impl=impl, expert_capacity=48, group_offset=o))
+    out = at(x, bank, sizes, jnp.int32(g))
+    own = ops.grouped_gemm(x, bank[g:2 * g], sizes, impl=impl,
+                           expert_capacity=48)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(own))
+    assert not np.array_equal(np.asarray(out),
+                              np.asarray(at(x, bank, sizes, jnp.int32(0))))
+    if impl == "xla_ragged":      # the same rows, their groups moved last
+        moved = ops.grouped_gemm(
+            x, jnp.concatenate([bank[:g], bank[g:2 * g]]),
+            jnp.concatenate([jnp.zeros_like(sizes), sizes]), impl=impl)
+        np.testing.assert_array_equal(np.asarray(moved), np.asarray(own))
+
+
 def test_grouped_gemm_empty_groups(rng):
     """Group-shrink guarantee: all-empty groups produce zeros + no NaN."""
     m, k, n, g = 32, 16, 16, 4
@@ -71,6 +97,12 @@ def test_blocks_chosen_from_shapes(k, n):
     assert gg.working_set(128, tk, tn) <= gg.VMEM_BUDGET
     if k * n >= 2048 * 1024:           # published widths: MiB-sized blocks
         assert tk * tn * 2 >= 2 * 2 ** 20, (tk, tn)
+    # no block as large that fits reads a row tile's x rows fewer times
+    for bk in gg._block_sizes(k):
+        bn = tk * tn // bk
+        if (n % bn == 0 and bn % 128 == 0
+                and gg.working_set(128, bk, bn) <= gg.VMEM_BUDGET):
+            assert gg.x_reads(k, n, tk, tn) <= gg.x_reads(k, n, bk, bn)
 
 
 def test_dead_tiles_fetch_the_last_live_block():
@@ -165,7 +197,7 @@ def test_ops_refuse_impl_they_do_not_have(op, impl, rng):
 
 
 def test_ops_auto_picks_from_platform():
-    """On the CPU "auto" is ragged_dot for the grouped GEMM and the plain
+    """On the CPU "auto" is xla_ragged for the grouped GEMM and the plain
     XLA lowering for the others; Pallas is what it picks on TPU."""
     assert ops._resolve("grouped_gemm", "auto", "xla_ragged",
                         ops._GEMM_IMPLS) == "xla_ragged"

@@ -50,8 +50,8 @@ def _dense_oracle(cfg, params, x):
     return out
 
 
-# pallas_interpret: the grouped-GEMM kernel under the vmap over logical
-# servers, as "auto" runs it on TPU
+# pallas_interpret: the grouped-GEMM kernel over every logical server's
+# experts, as "auto" runs it on TPU
 @pytest.mark.parametrize("gemm_impl", ["xla_ragged", "pallas_interpret"])
 def test_eaas_matches_dense_oracle(gemm_impl):
     cfg, params, x, rt = _setup(S=4)
@@ -149,3 +149,115 @@ def test_plain_reference_matches_dense_oracle():
         np.asarray(moe_layer_ref(params, x, cfg.moe, cfg.activation)),
         np.asarray(_dense_oracle(cfg, params, x), np.float32),
         rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------- servers folded into the groups
+
+def _one_server(tokens, expert_ids, scores, counts, w_gate, w_up, w_down,
+                local_table, impl):
+    """One server's batch, as each server ran it before the servers were
+    folded into one grouped GEMM: its rows sorted by its own slots, a
+    grouped GEMM over its L experts.  tokens (Clients, C, d)."""
+    from repro.kernels import ops as kops
+
+    Sc, C, d = tokens.shape
+    M = Sc * C
+    x = tokens.reshape(M, d)
+    eid = expert_ids.reshape(M)
+    sc = scores.reshape(M)
+    valid = (jnp.arange(C)[None, :] < counts[:, None]).reshape(M)
+    valid &= eid >= 0
+    L = w_gate.shape[0]
+    slot = jnp.where(valid, local_table[jnp.clip(eid, 0)], L)
+    hosted = slot >= 0
+    miss = jnp.sum(valid & ~hosted)
+    slot = jnp.where(hosted, slot, L)
+    order = jnp.argsort(slot)
+    xs = x[order]
+    group_sizes = jnp.bincount(slot, length=L + 1)[:L]
+    ecap = max(8, ((-(-(M * 5) // (4 * L))) + 7) // 8 * 8)
+    gg = lambda a, w: kops.grouped_gemm_counted(a, w, group_sizes, impl=impl,
+                                                expert_capacity=ecap)
+    h_gate, t_gate = gg(xs, w_gate)
+    h_up, t_up = gg(xs, w_up)
+    h = jax.nn.silu(h_gate.astype(jnp.float32)).astype(h_up.dtype) * h_up
+    y, t_down = gg(h, w_down)
+    y = y.astype(jnp.float32) * sc[order][:, None]
+    in_group = jnp.arange(M) < jnp.sum(group_sizes)
+    y = jnp.where((valid[order] & hosted[order] & in_group)[:, None], y, 0)
+    out = jnp.zeros((M, d), jnp.float32).at[order].set(y)
+    out = out.reshape(Sc, C, d).astype(tokens.dtype)
+    return out, expert_server.ServeStats(
+        miss=miss, served=jnp.sum(valid & hosted),
+        tiles_live=t_gate.live + t_up.live + t_down.live,
+        tiles=t_gate.total + t_up.total + t_down.total)
+
+
+def _per_server_serve(tokens, expert_ids, scores, counts, weights, *,
+                      impl="auto", layer=None):
+    """The oracle: the per-server path the folded ``serve`` replaced, the
+    S logical servers vmapped (a given layer sliced out first)."""
+    wg, wu, wd, table = weights
+    if layer is not None:
+        wg, wu, wd = wg[layer], wu[layer], wd[layer]
+    out, st = jax.vmap(lambda *a: _one_server(*a, impl=impl))(
+        tokens, expert_ids, scores, counts, wg, wu, wd, table)
+    return out, jax.tree.map(jnp.sum, st)
+
+
+def _failover_setup():
+    """4 servers of 8 experts, one redundant slot each (server 2's empty),
+    server 1 dead: expert 2 fails over to its replica on server 0, expert 3
+    has none and falls back to server 0, which does not host it (a miss).
+    Buffers of 128 rows a server, whole row tiles."""
+    E, S = 8, 4
+    red = np.array([[2], [0], [-1], [5]], np.int32)
+    mapping = emap.default_mapping(E, S, max_replicas=2)
+    mapping[:, 1] = -1
+    for s, e in ((0, 2), (1, 0), (3, 5)):
+        mapping[e, 1] = s
+    cfg, params, x, rt = _setup(S=S, redundant_table=red)
+    rt = rt._replace(mapping=jnp.asarray(mapping),
+                     alive=rt.alive.at[1].set(False), capacity=128)
+    return cfg, params, x, rt
+
+
+def _stacked(params, layers=3):
+    """params with a bank of ``layers`` layers whose experts differ."""
+    banks = {k: jnp.stack([v * (1.0 + 0.5 * i) for i in range(layers)])
+             for k, v in params["servers"].items()}
+    return dict(params, servers=banks)
+
+
+def _apply(params, x, cfg, rt, layer=None):
+    fn = jax.jit(lambda p, v, lay: eaas.eaas_moe_apply(
+        p, v, cfg.moe, rt, activation=cfg.activation, layer=lay))
+    return fn(params, x, layer)
+
+
+@pytest.mark.parametrize("gemm_impl", ["pallas_interpret", "xla_ragged"])
+@pytest.mark.parametrize("layer", [None, 2], ids=["bank", "stacked_layer2"])
+def test_folded_serve_matches_per_server_path(monkeypatch, gemm_impl,
+                                              layer):
+    """One grouped GEMM over every server's experts (group server·L + slot,
+    read at layer·S·L from a stacked bank) gives the per-server path's
+    outputs bit for bit, and the same misses, drops and row tiles, with
+    redundant slots, an empty slot, a dead server and routes to a server
+    that does not host the expert."""
+    cfg, params, x, rt = _failover_setup()
+    rt = rt._replace(gemm_impl=gemm_impl)
+    if layer is not None:
+        params = _stacked(params)
+        layer = jnp.int32(layer)
+    y, st = _apply(params, x, cfg, rt, layer)
+    monkeypatch.setattr(expert_server, "serve", _per_server_serve)
+    y_ref, st_ref = _apply(params, x, cfg, rt, layer)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_ref))
+    for name in ("miss", "dropped", "gemm_tiles_live", "gemm_tiles"):
+        assert int(getattr(st, name)) == int(getattr(st_ref, name)), name
+    assert int(st.miss) > 0 and int(st.dropped) == 0
+    if gemm_impl == "pallas_interpret":
+        assert 0 < int(st.gemm_tiles_live) < int(st.gemm_tiles)
+    if layer is not None:    # another layer's experts give another result
+        y_other, _ = _apply(params, x, cfg, rt, jnp.int32(1))
+        assert not np.array_equal(np.asarray(y), np.asarray(y_other))
